@@ -45,29 +45,13 @@ SCHEMA_VERSION = 2
 _REPO_ROOT = os.path.dirname(os.path.abspath(os.path.dirname(__file__)))
 
 # persistent compilation cache: scan-trajectory first calls cost 2.7-6.3 s
-# of compile per shape, which dominates smoke-scale CI lanes.  The cache
-# dir is env-overridable (CI points it at an actions/cache path and
-# JAX_NO_COMPILE_CACHE=1 opts out for clean cold-compile measurements);
-# cold vs warm seconds are recorded in the artifacts either way, so a
-# cache-warmed run is visible as cold ~= warm rather than invisible.
+# of compile per shape, which dominates smoke-scale CI lanes.  The directory
+# comes from repro.launch.compile_cache (JAX_COMPILATION_CACHE_DIR, else the
+# repo's .jax_cache); JAX_NO_COMPILE_CACHE=1 opts out for clean cold-compile
+# measurements.  Cold vs warm seconds are recorded in the artifacts either
+# way, so a cache-warmed run is visible as cold ~= warm rather than
+# invisible.
 COMPILE_CACHE_DIR = None
-
-
-def _enable_compile_cache():
-    global COMPILE_CACHE_DIR
-    if os.environ.get("JAX_NO_COMPILE_CACHE") == "1":
-        return None
-    d = os.environ.get("JAX_COMPILATION_CACHE_DIR",
-                       os.path.join(_REPO_ROOT, ".jax_cache"))
-    try:
-        jax.config.update("jax_compilation_cache_dir", d)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception as e:          # older jax: cache flags absent
-        print(f"# compilation cache unavailable: {e}")
-        return None
-    COMPILE_CACHE_DIR = d
-    print(f"# jax compilation cache: {d}")
-    return d
 
 
 def write_artifact(name: str, payload: dict, config: dict) -> None:
@@ -291,20 +275,6 @@ def _bench_placement_kernel(n: int, lanes: int) -> dict:
             "ensemble_s": ens_s, "scan_s": seq_s}
 
 
-def _scan_vs_host_parity(host, scan):
-    """Equivalence contract of the scanned core (see simulate_fleet_scan):
-    placements + counters exact, f64-vs-f32 accounting within rtol."""
-    counters = ("rank_sweeps", "arrivals_placed", "jobs_completed",
-                "jobs_dropped", "jobs_deferred", "migrations", "evictions")
-    exact = (np.array_equal(host.node_log, scan.node_log)
-             and np.array_equal(host.first_node, scan.first_node)
-             and all(getattr(host, f) == getattr(scan, f)
-                     for f in counters))
-    rel = float(abs(host.emissions_g - scan.emissions_g)
-                / max(abs(host.emissions_g), 1e-9))
-    return bool(exact and rel <= 1e-4), rel
-
-
 def _time_scan(fleet, traces, ridx, cfg, jobs):
     """(first_call_s, warm_s, result): cold call pays the lax.scan compile,
     second call is the steady-state trajectory time.  simulate_fleet_scan
@@ -333,7 +303,7 @@ def bench_sim_scale():
     import dataclasses
     from repro.core.scenarios import run_paper_experiment
     from repro.core.simulator import (SimConfig, generate_jobs,
-                                      simulate_fleet,
+                                      scan_vs_host_parity, simulate_fleet,
                                       synthetic_lifecycle_fleet)
     ns = tuple(int(x) for x in os.environ.get("SIM_NS", "4096").split(","))
     epochs = int(os.environ.get("SIM_EPOCHS", "168"))
@@ -372,7 +342,7 @@ def bench_sim_scale():
         # scanned core: compile+run, then steady state
         first_s, warm_s, s = _time_scan(fleet, traces, ridx, cfg, jobs)
         scan_us = warm_s * 1e6 / max(epochs, 1)
-        scan_parity, rel = _scan_vs_host_parity(a, s)
+        scan_parity, rel = scan_vs_host_parity(a, s)
         row(f"sim_scan_n{n}", scan_us,
             f"first_call_s={first_s:.2f};parity={scan_parity};"
             f"emissions_rel_err={rel:.2e};"
@@ -406,7 +376,7 @@ def bench_sim_scale():
         t0 = time.perf_counter()
         a = simulate_fleet(fleet, traces, ridx, cfg, jobs=jobs)
         host_s = time.perf_counter() - t0
-        scan_parity, rel = _scan_vs_host_parity(a, s)
+        scan_parity, rel = scan_vs_host_parity(a, s)
         speedup = host_s / max(scan_s, 1e-9)
         row(f"sim_scan_long_n{long_n}_t{long_epochs}",
             scan_s * 1e6 / long_epochs,
@@ -713,7 +683,7 @@ def bench_robustness():
     import hashlib
     from repro.core.faults import FaultConfig
     from repro.core.simulator import (SimConfig, generate_jobs,
-                                      simulate_fleet,
+                                      scan_vs_host_parity, simulate_fleet,
                                       simulate_fleet_ensemble,
                                       simulate_fleet_scan,
                                       synthetic_lifecycle_fleet)
@@ -839,7 +809,7 @@ def bench_robustness():
     pjobs = generate_jobs(pcfg)
     h = simulate_fleet(pf, ptr, pri, pcfg, jobs=pjobs)
     s = simulate_fleet_scan(pf, ptr, pri, pcfg, jobs=pjobs)
-    probe_ok, rel = _scan_vs_host_parity(h, s)
+    probe_ok, rel = scan_vs_host_parity(h, s)
     probe_ok &= all(getattr(h, f) == getattr(s, f) for f in
                     ("migrations_failed", "jobs_active_end",
                      "safe_epochs"))
@@ -1353,7 +1323,11 @@ def main() -> None:
     if unknown:
         raise SystemExit(f"unknown bench(es) {unknown}; "
                          f"choose from {list(BENCHES)}")
-    _enable_compile_cache()
+    global COMPILE_CACHE_DIR
+    if os.environ.get("JAX_NO_COMPILE_CACHE") != "1":
+        from repro.launch.compile_cache import enable_compile_cache
+        COMPILE_CACHE_DIR = enable_compile_cache()
+        print(f"# jax compilation cache: {COMPILE_CACHE_DIR}")
     print("name,us_per_call,derived")
     for n in names:
         BENCHES[n]()

@@ -92,36 +92,11 @@ from repro.core.energy import DEFAULT_ENERGY, EnergyModel
 from repro.core.fleet import IDLE_POWER_FRAC, Fleet
 from repro.core.ranking import RankWeights
 
-# ``optimization_barrier`` (the rounding pin of the exact-parity scoring
-# path) has no batching rule in this jax version, which would bar the
-# whole engine from ``vmap`` — the batched ensemble simulator
-# (``simulator.simulate_fleet_ensemble``) maps the scanned core over a
-# (seed x policy) axis.  The barrier is elementwise identity per operand,
-# so the rule is pure pass-through: bind the primitive on the batched
-# operands and keep each operand's batch dim.  Registered idempotently so
-# newer jax versions that ship the rule win.
-def _register_barrier_batching() -> None:
-    try:
-        from jax._src.lax.lax import optimization_barrier_p
-        from jax.interpreters import batching
-    except ImportError:      # layout changed: assume the rule exists
-        return
-    if optimization_barrier_p in batching.primitive_batchers:
-        return
-
-    def _rule(args, dims):
-        return optimization_barrier_p.bind(*args), dims
-
-    batching.primitive_batchers[optimization_barrier_p] = _rule
-
-
-_register_barrier_batching()
-
 
 def rounding_pin(xp):
     """The f32 rounding pin for ``xp``-generic parity code: the
-    ``optimization_barrier`` identity under jnp (vmap-batchable via the
-    rule above), a plain identity on numpy.  The QPS router
+    ``optimization_barrier`` identity under jnp (which ``vmap`` batches
+    natively), a plain identity on numpy.  The QPS router
     (``repro.core.router``) pins its greenness-blend multiply with this
     so the host and scanned drivers cannot diverge by operator fusion —
     the same discipline this module's scoring path applies at every
@@ -610,7 +585,8 @@ def place_lifecycle_batched(fleet: Fleet, demands: jax.Array,
                             interpret: Optional[bool] = None,
                             capacity: Optional[jax.Array] = None,
                             n_events: Optional[jax.Array] = None,
-                            energy: Optional[EnergyModel] = None):
+                            energy: Optional[EnergyModel] = None,
+                            mesh: Optional[jax.sharding.Mesh] = None):
     """Arrival-only lifecycle placement over an explicit leading lane axis
     — the batched-ensemble twin of ``place_lifecycle_shortlist`` (with
     ``eager_sweep``) and ``place_lifecycle_full_rerank``.
@@ -645,7 +621,10 @@ def place_lifecycle_batched(fleet: Fleet, demands: jax.Array,
     the batched ``lax.top_k``; with ``use_kernel`` the round-boundary
     sweep is instead ONE Pallas launch on a (stalled-lanes × node-tiles)
     grid (``repro.kernels.ops.maiz_ranking_topk_batched``), per-lane
-    identical to the sequential engine's kernel sweep."""
+    identical to the sequential engine's kernel sweep.  ``mesh`` is the
+    ensemble's device mesh when its buffers are sharded: the kernel sweep
+    then runs per device under ``shard_map`` (the jnp path needs no
+    mesh; XLA partitions it)."""
     L, N = fleet.capacity.shape
     E = demands.shape[1]
     K = min(max(shortlist, 1), N)
@@ -725,7 +704,8 @@ def place_lifecycle_batched(fleet: Fleet, demands: jax.Array,
             return maiz_ranking_topk_batched(
                 ec, fleet.pue, fleet.ci_now, fleet.ci_forecast,
                 fleet.flops_per_j, fleet.sched_term, weights.as_array(),
-                k=k_cand, lohi=ctx["lohi"], interpret=interpret, **kw)
+                k=k_cand, lohi=ctx["lohi"], interpret=interpret, mesh=mesh,
+                **kw)
     else:
         def sweep_topk(cap):
             scores = _ctx_scores(cap, ctx, weights)

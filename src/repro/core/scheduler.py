@@ -137,6 +137,7 @@ def place_jobs(fleet: Fleet, demands: jax.Array,
                horizon_h: float = 1.0, *,
                engine: str = "auto", shortlist: int = 32,
                use_kernel: bool = False,
+               interpret: Optional[bool] = None,
                energy: Optional[EnergyModel] = None) -> Placement:
     """Greedy: jobs in given order take the best-ranked node with capacity.
 
@@ -148,7 +149,9 @@ def place_jobs(fleet: Fleet, demands: jax.Array,
     tile-merged top-``shortlist`` and places in O(N + J·K);
     ``engine="full"`` is the O(J·N) per-job re-rank oracle the shortlist
     path is bit-identical to (see ``repro.core.placement``).
-    ``use_kernel`` routes epoch sweeps through the fused Pallas kernel.
+    ``use_kernel`` routes epoch sweeps through the fused Pallas kernel;
+    ``interpret`` forces/disables its Pallas interpret mode (None = auto
+    by backend).
 
     The win is measured in rank sweeps (the memory-bound quantity on TPU:
     5 vs 256 at N=65536, J=256 — see BENCH_placement.json).  On CPU with
@@ -163,7 +166,7 @@ def place_jobs(fleet: Fleet, demands: jax.Array,
     if engine == "shortlist":
         r = placement.place_jobs_shortlist(
             fleet, demands, weights, horizon_h, shortlist=shortlist,
-            use_kernel=use_kernel, energy=energy)
+            use_kernel=use_kernel, interpret=interpret, energy=energy)
     elif engine == "full":
         r = placement.place_jobs_full_rerank(fleet, demands, weights,
                                              horizon_h, energy=energy)
@@ -174,7 +177,7 @@ def place_jobs(fleet: Fleet, demands: jax.Array,
 
 place_jobs_jit = jax.jit(place_jobs,
                          static_argnames=("engine", "shortlist",
-                                          "use_kernel"))
+                                          "use_kernel", "interpret"))
 
 
 def place_events(fleet: Fleet, demands: jax.Array, nodes: jax.Array,

@@ -94,6 +94,9 @@ class SimConfig:
     engine: str = "shortlist"       # shortlist | full | blind | spread
     shortlist: int = 64
     use_kernel: bool = False
+    # Pallas interpret mode of the use_kernel sweep: None = by backend
+    # (interpreted off-TPU), False = the compiled Mosaic kernel only
+    interpret: Optional[bool] = None
     horizon_h: int = 24             # FCFP forecast horizon
     history_h: int = 336            # trailing window fed to fit_forecast
     # --- arrival process (seeded, deterministic) ---
@@ -316,6 +319,7 @@ def _place_epoch(pue, power_kw, chips_total, straggler, flops_per_j,
     post-release capacity as ``cap_start`` — identical final state, fewer
     loop iterations."""
     engine, shortlist, use_kernel, weights = statics[:4]
+    interpret = statics[9]
     fleet = Fleet(ci_now=ci_now.astype(jnp.float32),
                   ci_forecast=ci_fc.astype(jnp.float32),
                   pue=pue, power_kw=power_kw, capacity=cap_ctx,
@@ -329,6 +333,7 @@ def _place_epoch(pue, power_kw, chips_total, straggler, flops_per_j,
         r = place_lifecycle_shortlist(fleet, demands, nodes, weights,
                                       horizon_h=1.0, shortlist=shortlist,
                                       use_kernel=use_kernel,
+                                      interpret=interpret,
                                       capacity=cap_start,
                                       n_events=n_events,
                                       eager_sweep=eager_sweep,
@@ -356,7 +361,7 @@ def _epoch_core(traces, ridx, pue, power_kw, chips_total, straggler,
     ``lax.scan``, with the forecast batched over epochs up front (bitwise
     equal: it only depends on the static traces)."""
     (engine, shortlist, use_kernel, weights, horizon_h, history_h,
-     use_forecast, defer_window, fc_fallback) = statics
+     use_forecast, defer_window, fc_fallback, _) = statics
     ci_now_r = jax.lax.dynamic_slice_in_dim(traces, t, 1, axis=1)[:, 0]
     ci_now = ci_now_r[ridx]
     if use_forecast:
@@ -536,7 +541,8 @@ def simulate_fleet(fleet0: Fleet, region_ci: np.ndarray, ridx: np.ndarray,
                cfg.weights.graph_key(),
                cfg.horizon_h, cfg.history_h,
                cfg.use_forecast and not blind,
-               pol.defer_window(cfg.defer_max_h), fc_fallback)
+               pol.defer_window(cfg.defer_max_h), fc_fallback,
+               cfg.interpret)
     overhead_s = cfg.migration_overhead_h * 3600.0
     n_ten = int(cfg.n_tenants)
     if n_ten and jobs.tenant is None:
@@ -1012,7 +1018,7 @@ def _scan_plan(cfg: SimConfig, jobs: JobSchedule, pol: Policy,
                     m_evict=m_evict, arr_ids=arr_ids)
 
 
-def _traj_scan(arrs, statics, dims, ensemble: bool):
+def _traj_scan(arrs, statics, dims, ensemble: bool, mesh=None):
     """The whole trajectory as one ``lax.scan``: fixed-size slot table +
     padded event buffers around the shared ``_place_epoch`` epoch graph.
 
@@ -1027,7 +1033,8 @@ def _traj_scan(arrs, statics, dims, ensemble: bool):
     sweep work per sweep-round instead of per event (vmapping the
     sequential engine would execute both ``lax.cond`` branches per
     event).  ``ensemble=False`` is the unchanged sequential core:
-    identical ops, one trajectory.
+    identical ops, one trajectory.  ``mesh`` is the ensemble's device
+    mesh when its buffers are sharded (see ``_shard_over_e``).
 
     Hot-path structure (all bitwise-neutral vs the host loop's per-epoch
     graph):
@@ -1066,8 +1073,8 @@ def _traj_scan(arrs, statics, dims, ensemble: bool):
     # (``en_*`` scalars, lowered host-side by ``_build_arrs``) — an
     # (idle-frac x embodied x marginal) calibration grid shares this one
     # compiled trajectory, on the Pallas path too (the kernel consumes
-    # the same scalars through its en_* SMEM block).
-    use_kernel = statics[2]
+    # the same scalars through its en_* VMEM block).
+    use_kernel, interpret = statics[2], statics[9]
     if slo:
         arange_e = jnp.arange(n_narr, dtype=jnp.int32)
         # effective queue capacity: a traced per-run scalar <= the static
@@ -1605,9 +1612,9 @@ def _traj_scan(arrs, statics, dims, ensemble: bool):
                       chips_total=arrs["chips_total"])
         out_c, cap2, n_sw = place_lifecycle_batched(
             fleet, mid["dem"], weights, horizon_h=1.0, engine=engine,
-            shortlist=shortlist, use_kernel=use_kernel,
+            shortlist=shortlist, use_kernel=use_kernel, interpret=interpret,
             capacity=mid["cap_start"], n_events=mid["n_ev"],
-            energy=em_tr)
+            energy=em_tr, mesh=mesh)
         return vpost(arrs, mid, out_c, cap2, n_sw)
 
     init = (arrs["capacity"], jnp.zeros((L, N), jnp.int32),
@@ -1631,14 +1638,14 @@ _scan_trajectory = jax.jit(_scan_traj_impl,
                            static_argnames=("statics", "dims"))
 
 
-@functools.partial(jax.jit, static_argnames=("statics", "dims"),
+@functools.partial(jax.jit, static_argnames=("statics", "dims", "mesh"),
                    donate_argnums=(0,))
-def _ensemble_trajectory(arrs, statics, dims):
+def _ensemble_trajectory(arrs, statics, dims, mesh=None):
     """E stacked trajectories as ONE compiled program (see ``_traj_scan``
     with ``ensemble=True``).  The stacked input buffers are donated (they
     are rebuilt per call; the scan carries alias them on backends that
     support donation)."""
-    return _traj_scan(arrs, statics, dims, ensemble=True)
+    return _traj_scan(arrs, statics, dims, ensemble=True, mesh=mesh)
 
 
 @dataclasses.dataclass
@@ -1682,7 +1689,8 @@ def _prepare_scan_run(fleet0: Fleet, region_ci: np.ndarray,
     statics = (cfg.engine, cfg.shortlist, cfg.use_kernel,
                cfg.weights.graph_key(),
                cfg.horizon_h, cfg.history_h, cfg.use_forecast,
-               pol.defer_window(cfg.defer_max_h), fc_fallback)
+               pol.defer_window(cfg.defer_max_h), fc_fallback,
+               cfg.interpret)
     fplan = None
     if cfg.faults is not None:
         fplan = plan_faults(cfg.faults, np.asarray(region_ci, np.float64),
@@ -1996,6 +2004,25 @@ def simulate_fleet_scan(fleet0: Fleet, region_ci: np.ndarray,
                         [np.asarray(y) for y in ys])
 
 
+_PARITY_COUNTERS = ("rank_sweeps", "arrivals_placed", "jobs_completed",
+                    "jobs_dropped", "jobs_deferred", "migrations",
+                    "evictions")
+
+
+def scan_vs_host_parity(host: SimResult, scan: SimResult
+                        ) -> Tuple[bool, float]:
+    """The scanned core's equivalence contract (see
+    ``simulate_fleet_scan``): placements and counters exact, f64-vs-f32
+    accounting within rtol 1e-4.  Returns (holds, emissions rel. error)."""
+    exact = (np.array_equal(host.node_log, scan.node_log)
+             and np.array_equal(host.first_node, scan.first_node)
+             and all(getattr(host, f) == getattr(scan, f)
+                     for f in _PARITY_COUNTERS))
+    rel = float(abs(host.emissions_g - scan.emissions_g)
+                / max(abs(host.emissions_g), 1e-9))
+    return bool(exact and rel <= 1e-4), rel
+
+
 def simulate_fleet_ensemble(runs, *, pad_plan: bool = True,
                             shard=False) -> list:
     """Run an ensemble of trajectories as ONE compiled, ONE dispatched
@@ -2032,13 +2059,15 @@ def simulate_fleet_ensemble(runs, *, pad_plan: bool = True,
     (``distributed.sharding.ensemble_mesh``): the leftover device factor
     splits the *node* axis of the (E, N) fleet buffers, for fleets that do
     not fit one device — the tile-local top-k merge is unchanged (XLA
-    concatenates per-shard candidates before the host-side ``lax.top_k``).
+    concatenates per-shard candidates before the ``lax.top_k``).
 
     ``use_kernel=True`` members run the batched Pallas sweep — one
     (stalled-lanes × node-tiles) kernel launch per placement round
     (``placement.place_lifecycle_batched``), per-lane bit-identical to
     the sequential scan driver (interpret mode on CPU, compiled on
-    TPU)."""
+    TPU).  XLA cannot partition a compiled Pallas kernel, so on a sharded
+    ensemble the sweep runs per device under ``shard_map`` over the same
+    mesh (``ops.maiz_ranking_topk_batched``)."""
     preps = []
     for spec in runs:
         jobs = spec[4] if len(spec) > 4 else None
@@ -2054,8 +2083,9 @@ def simulate_fleet_ensemble(runs, *, pad_plan: bool = True,
         built = [_build_arrs(m, dims, jp, nmax) for m in members]
         stacked = {k: jnp.stack([b[k] for b in built]) for k in built[0]}
         del built
+        mesh = None
         if shard:
-            stacked = _shard_over_e(
+            stacked, mesh = _shard_over_e(
                 stacked, axes="en" if shard == "en" else "e")
         with warnings.catch_warnings():
             # input donation is best-effort: only the lanes that alias a
@@ -2063,7 +2093,8 @@ def simulate_fleet_ensemble(runs, *, pad_plan: bool = True,
             warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable")
             carry, ys = jax.block_until_ready(
-                _ensemble_trajectory(stacked, members[0].statics, dims))
+                _ensemble_trajectory(stacked, members[0].statics, dims,
+                                     mesh=mesh))
         carry = [np.asarray(c) for c in carry]
         ys = [np.asarray(y) for y in ys]
         for lane, i in enumerate(idxs):
@@ -2089,7 +2120,9 @@ def _shard_over_e(stacked, axes: str = "e"):
     additionally split the node axis of the (E, N) fleet buffers over the
     leftover device factor — for fleets that do not fit one device; XLA
     inserts the cross-shard collectives for the ``lax.top_k`` candidate
-    merge and argmin reductions.  Either way a single device is a no-op."""
+    merge and argmin reductions.  Either way a single device is a no-op.
+    Returns the buffers and the mesh they were laid out on (None when
+    nothing was sharded)."""
     devs = jax.devices()
     E = next(iter(stacked.values())).shape[0]
     P = jax.sharding.PartitionSpec
@@ -2097,19 +2130,19 @@ def _shard_over_e(stacked, axes: str = "e"):
         nd = max((d for d in range(1, len(devs) + 1) if E % d == 0),
                  default=1)
         if nd <= 1:
-            return stacked
+            return stacked, None
         mesh = jax.sharding.Mesh(np.array(devs[:nd]), ("e",))
         sh = jax.sharding.NamedSharding(mesh, P("e"))
-        return {k: jax.device_put(v, sh) for k, v in stacked.items()}
+        return {k: jax.device_put(v, sh) for k, v in stacked.items()}, mesh
     if axes != "en":
         raise ValueError(f"shard axes must be 'e' or 'en', got {axes!r}")
     from repro.distributed.sharding import ensemble_mesh
     mesh = ensemble_mesh(E, stacked["capacity"].shape[1], devs)
     if mesh.devices.size <= 1:
-        return stacked
+        return stacked, None
     return {k: jax.device_put(v, jax.sharding.NamedSharding(
         mesh, P("e", "n") if k in _NODE_AXIS_KEYS else P("e")))
-        for k, v in stacked.items()}
+        for k, v in stacked.items()}, mesh
 
 
 # ---------------------------------------------------------------------------

@@ -6,9 +6,8 @@ memory-bound sweeps over the node axis, each touching every input stream
 exactly once:
 
 sweep 1 (``_lohi_kernel``)  — per (8, 128) VMEM tile, compute the Eq. 1
-    terms and reduce their tile-local (lo, hi); the host folds the per-tile
-    partials into the global (R, 2) min-max normalizers.  (Previously this
-    pre-pass materialized a stacked (R, N) term array in HBM — a third sweep.)
+    terms and reduce their tile-local (lo, hi); the wrapper folds the
+    per-tile partials into the global (R, 2) min-max normalizers.
 
 sweep 2 (``_topk_kernel``) — per tile:
 
@@ -18,18 +17,19 @@ sweep 2 (``_topk_kernel``) — per tile:
     [+ w_m·n(mcfp) when the EnergyModel scalars are threaded in — see below]
     tile-local top-k (scores + global indices) by iterative min-extraction
 
-where n(·) is min-max normalization with the sweep-1 lo/hi.  The tile top-k's
-are merged on the host by one ``lax.top_k`` over nt·k candidates, giving the
-exact global shortlist the placement engine (``repro.core.placement``)
-consumes.  Ties break toward the lower node index at every stage (extraction
-order within a tile, tile order across tiles, ``lax.top_k`` stability), so
-the merged shortlist is the lexicographic (score, index) head — identical to
-``jnp.argmin`` / stable-sort semantics.
+where n(·) is min-max normalization with the sweep-1 lo/hi.  The tile
+top-k's are merged after the kernel by one ``lax.top_k`` over nt·k
+candidates, giving the exact global shortlist the placement engine
+(``repro.core.placement``) consumes.  Ties break toward the lower node
+index at every stage (extraction order within a tile, tile order across
+tiles, ``lax.top_k`` stability), so the merged shortlist is the
+lexicographic (score, index) head — identical to ``jnp.argmin`` /
+stable-sort semantics.
 
 **Generalized score (EnergyModel + marginal CFP).**  The historical kernel
 baked the four-term score; both sweeps now optionally accept three extra
 node streams — ``pk`` (full-load power·horizon), ``cap`` (free chips, f32)
-and ``ct`` (total chips, f32) — plus one (1, 4) SMEM scalar block
+and ``ct`` (total chips, f32) — plus one (1, 4) VMEM block
 ``en = [idle_frac, dyn_frac, embodied·horizon, w_marginal]``.  When present,
 the kernels compute the Eq. 1 marginal-CFP term in-tile with the same op
 order as ``placement.frozen_ctx`` (``a_now = (pk·pue)·ci``, per-chip dynamic
@@ -50,6 +50,12 @@ sequential kernels run on that lane.
 Padding: arrays are padded up to the 1024-node tile; a scalar ``n_valid``
 masks padded lanes out of both the lo/hi reduction and the score output
 (padded scores are +inf, so they can never enter a shortlist).
+
+**Output layout.**  Mosaic accepts only (8, 128)-aligned blocks (or whole
+arrays), so every per-tile result — the (lo, hi) pairs of sweep 1, the
+top-k scores and node ids of sweep 2 — is assembled in registers into one
+lane-dense (8, 128) tile (``_put``) and stored once per grid step; the
+wrappers fold or slice those tiles (``_fold_lohi``, ``_tile_cands``).
 
 ``repro.kernels.ref.maiz_ranking_ref`` is the pure-jnp oracle;
 ``repro.core.ranking.maiz_ranking`` is the paper-faithful module
@@ -109,7 +115,7 @@ def _tile_mcfp(pk, pue, ci, cap, ct, en):
     with: ``a_now = (pk·pue)·ci``; per-chip dynamic carbon for running
     nodes; the idle-floor + amortized-embodied wake price charged only to
     fully idle ones.  ``en = [idle_frac, dyn_frac, embodied·horizon, w_m]``
-    lives in a (1, 4) SMEM scalar block."""
+    lives in a (1, 4) VMEM block."""
     an = pk.astype(jnp.float32) * pue.astype(jnp.float32)
     an = an * ci.astype(jnp.float32)
     ct = ct.astype(jnp.float32)
@@ -141,17 +147,40 @@ def _tile_score(terms, lohi, w, w5):
     return score
 
 
-def _tile_topk(score, fids, k, tile_base, tmin_write, targ_write):
+def _tile_topk(score, fids, k, tile_base):
     """Unrolled min-extraction: k is small and static, keeping everything 2D
     and avoiding dynamic ref indexing.  Equal scores yield the lower flat id
-    first, matching jnp.argmin's first-occurrence rule."""
+    first, matching jnp.argmin's first-occurrence rule.  Returns the
+    lane-dense (tmin, targ) tiles: candidate ``kk`` sits in row 0, lane
+    ``kk`` (see ``_put``)."""
+    tmin = jnp.zeros((SUBLANES, LANES), jnp.float32)
+    targ = jnp.zeros((SUBLANES, LANES), jnp.int32)
     cur = score
     for kk in range(k):
         m = jnp.min(cur)
         pos = jnp.min(jnp.where(cur == m, fids, TILE))
-        tmin_write(kk, m)
-        targ_write(kk, pos + tile_base)
+        tmin = _put(tmin, fids, kk, m)
+        targ = _put(targ, fids, kk, pos + tile_base)
         cur = jnp.where(fids == pos, jnp.inf, cur)
+    return tmin, targ
+
+
+def _put(tile, fids, slot, v):
+    """Select scalar ``v`` into flat slot ``slot`` (row ``slot // 128``,
+    lane ``slot % 128``) of an (8, 128) tile.  Per-tile results are
+    assembled this way and stored as one whole tile per grid step, the
+    only output block shape Mosaic accepts for a per-tile result."""
+    return jnp.where(fids == slot, v, tile)
+
+
+def _lohi_tile(terms, valid, fids):
+    """Tile-local (lo, hi) of every term: lo of term i in row 0, lane i;
+    hi in row 1, lane i (read back by ``_fold_lohi``)."""
+    out = jnp.zeros((SUBLANES, LANES), jnp.float32)
+    for i, t in enumerate(terms):
+        out = _put(out, fids, i, jnp.min(jnp.where(valid, t, _BIG)))
+        out = _put(out, fids, LANES + i, jnp.max(jnp.where(valid, t, -_BIG)))
+    return out
 
 
 def _read_terms(ec_ref, pue_ref, ci_ref, fc_ref, eff_ref, sw_ref, rest,
@@ -174,14 +203,12 @@ def _read_terms(ec_ref, pue_ref, ci_ref, fc_ref, eff_ref, sw_ref, rest,
 
 def _lohi_kernel(n_ref, ec_ref, pue_ref, ci_ref, fc_ref, eff_ref, sw_ref,
                  *rest):
-    lo_ref, hi_ref = rest[-2:]
+    out_ref = rest[-1]
     terms, _ = _read_terms(ec_ref, pue_ref, ci_ref, fc_ref, eff_ref, sw_ref,
-                           rest, 2)
-    ti = pl.program_id(0)
-    valid = _flat_ids() + ti * TILE < n_ref[0, 0]
-    for i, t in enumerate(terms):
-        lo_ref[0, i] = jnp.min(jnp.where(valid, t, _BIG))
-        hi_ref[0, i] = jnp.max(jnp.where(valid, t, -_BIG))
+                           rest, 1)
+    fids = _flat_ids()
+    valid = fids + pl.program_id(0) * TILE < n_ref[0, 0]
+    out_ref[...] = _lohi_tile(terms, valid, fids)
 
 
 def _topk_kernel(n_ref, ec_ref, pue_ref, ci_ref, fc_ref, eff_ref, sw_ref,
@@ -195,23 +222,19 @@ def _topk_kernel(n_ref, ec_ref, pue_ref, ci_ref, fc_ref, eff_ref, sw_ref,
     score = _tile_score(terms, lohi_ref[...], w_ref[...], w5)
     score = jnp.where(valid, score, jnp.inf)
     score_ref[...] = score
-    _tile_topk(score, fids, k, ti * TILE,
-               lambda kk, m: tmin_ref.__setitem__((0, kk), m),
-               lambda kk, p: targ_ref.__setitem__((0, kk), p))
+    tmin_ref[...], targ_ref[...] = _tile_topk(score, fids, k, ti * TILE)
 
 
 def _lohi_kernel_b(n_ref, ec_ref, pue_ref, ci_ref, fc_ref, eff_ref, sw_ref,
                    *rest):
     """Batched twin on a (lane, tile) grid; every per-lane ref carries a
     leading unit lane-block axis that ``_read_terms`` peels off."""
-    lo_ref, hi_ref = rest[-2:]
+    out_ref = rest[-1]
     terms, _ = _read_terms(ec_ref, pue_ref, ci_ref, fc_ref, eff_ref, sw_ref,
-                           rest, 2, lane=0)
-    ti = pl.program_id(1)
-    valid = _flat_ids() + ti * TILE < n_ref[0, 0]
-    for i, t in enumerate(terms):
-        lo_ref[0, 0, i] = jnp.min(jnp.where(valid, t, _BIG))
-        hi_ref[0, 0, i] = jnp.max(jnp.where(valid, t, -_BIG))
+                           rest, 1, lane=0)
+    fids = _flat_ids()
+    valid = fids + pl.program_id(1) * TILE < n_ref[0, 0]
+    out_ref[0] = _lohi_tile(terms, valid, fids)
 
 
 def _topk_kernel_b(n_ref, ec_ref, pue_ref, ci_ref, fc_ref, eff_ref, sw_ref,
@@ -225,9 +248,20 @@ def _topk_kernel_b(n_ref, ec_ref, pue_ref, ci_ref, fc_ref, eff_ref, sw_ref,
     score = _tile_score(terms, lohi_ref[0], w_ref[...], w5)
     score = jnp.where(valid, score, jnp.inf)
     score_ref[0] = score
-    _tile_topk(score, fids, k, ti * TILE,
-               lambda kk, m: tmin_ref.__setitem__((0, 0, kk), m),
-               lambda kk, p: targ_ref.__setitem__((0, 0, kk), p))
+    tmin_ref[0], targ_ref[0] = _tile_topk(score, fids, k, ti * TILE)
+
+
+def _fold_lohi(tiles, nt, r):
+    """Fold the per-tile ``_lohi_tile`` outputs ((..., nt·8, 128)) into
+    the global (..., R, 2) normalizers."""
+    t = tiles.reshape(*tiles.shape[:-2], nt, SUBLANES, LANES)
+    return jnp.stack([t[..., 0, :r].min(-2), t[..., 1, :r].max(-2)], axis=-1)
+
+
+def _tile_cands(tiles, nt, k):
+    """The (..., nt, k) tile top-k candidates from row 0 of each per-tile
+    ``_tile_topk`` output tile."""
+    return tiles.reshape(*tiles.shape[:-2], nt, SUBLANES, LANES)[..., 0, :k]
 
 
 def _node_args(arrs, nt):
@@ -268,18 +302,18 @@ def maiz_lohi_pallas(ec, pue, ci_now, ci_fc, eff, sched, n_valid, *,
     arrs = [ec, pue, ci_now, ci_fc, eff, sched]
     if marginal:
         arrs += [pk, cap, ct]
-    args, _ = _node_args(arrs, nt)
+    args, shape2d = _node_args(arrs, nt)
     en_specs, en_ops = _marginal_ops(marginal, en)
     r = 5 if marginal else 4
-    lo, hi = pl.pallas_call(
+    tiles = pl.pallas_call(
         _lohi_kernel,
         grid=(nt,),
         in_specs=[_SCALAR_SPEC] + [_NODE_SPEC] * len(args) + en_specs,
-        out_specs=[pl.BlockSpec((1, r), lambda t: (t, 0))] * 2,
-        out_shape=[jax.ShapeDtypeStruct((nt, r), jnp.float32)] * 2,
+        out_specs=_NODE_SPEC,
+        out_shape=jax.ShapeDtypeStruct(shape2d, jnp.float32),
         interpret=interpret,
     )(n_valid, *args, *en_ops)
-    return jnp.stack([lo.min(0), hi.max(0)], axis=-1)
+    return _fold_lohi(tiles, nt, r)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
@@ -307,19 +341,16 @@ def maiz_topk_pallas(ec, pue, ci_now, ci_fc, eff, sched, n_valid, lohi,
             pl.BlockSpec((r, 2), lambda t: (0, 0)),      # lo/hi
             pl.BlockSpec((1, 4), lambda t: (0, 0)),      # weights
         ],
-        out_specs=[
-            _NODE_SPEC,
-            pl.BlockSpec((1, k), lambda t: (t, 0)),
-            pl.BlockSpec((1, k), lambda t: (t, 0)),
-        ],
+        out_specs=[_NODE_SPEC] * 3,
         out_shape=[
             jax.ShapeDtypeStruct(shape2d, jnp.float32),
-            jax.ShapeDtypeStruct((nt, k), jnp.float32),
-            jax.ShapeDtypeStruct((nt, k), jnp.int32),
+            jax.ShapeDtypeStruct(shape2d, jnp.float32),
+            jax.ShapeDtypeStruct(shape2d, jnp.int32),
         ],
         interpret=interpret,
     )(n_valid, *args, *en_ops, lohi, weights.reshape(1, 4))
-    return scores.reshape(n), tmin, targ
+    return (scores.reshape(n), _tile_cands(tmin, nt, k),
+            _tile_cands(targ, nt, k))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -339,15 +370,16 @@ def maiz_lohi_pallas_b(ec, pue, ci_now, ci_fc, eff, sched, n_valid, *,
     args = [a.reshape(L, nt * SUBLANES, LANES) for a in arrs]
     en_specs, en_ops = _marginal_ops(marginal, en, per_lane=True)
     r = 5 if marginal else 4
-    lo, hi = pl.pallas_call(
+    tiles = pl.pallas_call(
         _lohi_kernel_b,
         grid=(L, nt),
         in_specs=[_SCALAR_SPEC_B] + [_NODE_SPEC_B] * len(args) + en_specs,
-        out_specs=[pl.BlockSpec((1, 1, r), lambda l, t: (l, t, 0))] * 2,
-        out_shape=[jax.ShapeDtypeStruct((L, nt, r), jnp.float32)] * 2,
+        out_specs=_NODE_SPEC_B,
+        out_shape=jax.ShapeDtypeStruct((L, nt * SUBLANES, LANES),
+                                       jnp.float32),
         interpret=interpret,
     )(n_valid, *args, *en_ops)
-    return jnp.stack([lo.min(1), hi.max(1)], axis=-1)
+    return _fold_lohi(tiles, nt, r)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
@@ -377,16 +409,13 @@ def maiz_topk_pallas_b(ec, pue, ci_now, ci_fc, eff, sched, n_valid, lohi,
             pl.BlockSpec((1, r, 2), lambda l, t: (l, 0, 0)),   # lo/hi
             pl.BlockSpec((1, 4), lambda l, t: (0, 0)),         # weights
         ],
-        out_specs=[
-            _NODE_SPEC_B,
-            pl.BlockSpec((1, 1, k), lambda l, t: (l, t, 0)),
-            pl.BlockSpec((1, 1, k), lambda l, t: (l, t, 0)),
-        ],
+        out_specs=[_NODE_SPEC_B] * 3,
         out_shape=[
             jax.ShapeDtypeStruct((L, nt * SUBLANES, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((L, nt, k), jnp.float32),
-            jax.ShapeDtypeStruct((L, nt, k), jnp.int32),
+            jax.ShapeDtypeStruct((L, nt * SUBLANES, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((L, nt * SUBLANES, LANES), jnp.int32),
         ],
         interpret=interpret,
     )(n_valid, *args, *en_ops, lohi, weights.reshape(1, 4))
-    return scores.reshape(L, n), tmin, targ
+    return (scores.reshape(L, n), _tile_cands(tmin, nt, k),
+            _tile_cands(targ, nt, k))

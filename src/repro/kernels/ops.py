@@ -1,7 +1,9 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU so the same call sites work in this
-CPU container (Pallas interpret mode) and on real TPU (compiled kernels).
+``interpret=None`` resolves by backend: the Pallas interpreter off-TPU
+(how the CPU test suite runs the kernels) and the compiled Mosaic kernel on
+a TPU.  Callers that must never fall back to the interpreter pass
+``interpret=False`` (``chip_smoke.py``, ``tests/test_tpu_compile.py``).
 """
 from __future__ import annotations
 
@@ -107,7 +109,8 @@ def maiz_ranking_topk_batched(ec, pue, ci_now, ci_fc, eff, sched, weights, *,
                               cap: Optional[jax.Array] = None,
                               chips_total: Optional[jax.Array] = None,
                               en: Optional[jax.Array] = None,
-                              interpret: Optional[bool] = None
+                              interpret: Optional[bool] = None,
+                              mesh: Optional[jax.sharding.Mesh] = None
                               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Batched ``maiz_ranking_topk`` over a leading ensemble-lane axis.
 
@@ -118,7 +121,19 @@ def maiz_ranking_topk_batched(ec, pue, ci_now, ci_fc, eff, sched, weights, *,
     Each lane's (scores, topk_scores, topk_nodes) is identical to the
     sequential ``maiz_ranking_topk`` on that lane — the round-boundary
     sweep of ``placement.place_lifecycle_batched`` relies on this for
-    ensemble/scan-driver parity."""
+    ensemble/scan-driver parity.
+
+    ``mesh`` (an ``("e",)`` or ``("e", "n")`` mesh, see
+    ``distributed.sharding.ensemble_mesh``) runs the sweep per device
+    under ``shard_map``: XLA cannot partition a compiled Pallas kernel
+    itself.  Lanes split over ``"e"`` and nodes over ``"n"``; each
+    node shard merges its own candidates, and one more ``lax.top_k`` over
+    the shards' candidates (in node order) gives the same shortlist as
+    one device.  Needs ``lohi``: normalizers are global, not per shard."""
+    if mesh is not None:
+        return _topk_batched_sharded(
+            mesh, (ec, pue, ci_now, ci_fc, eff, sched), weights, k, lohi,
+            dict(pk=pk, cap=cap, chips_total=chips_total, en=en), interpret)
     if interpret is None:
         interpret = _default_interpret()
     L, n = ec.shape
@@ -148,6 +163,41 @@ def maiz_ranking_topk_batched(ec, pue, ci_now, ci_fc, eff, sched, weights, *,
         return scores, -neg, pos.astype(jnp.int32)
     neg, pos = jax.lax.top_k(-tmin.reshape(L, -1), k_out)
     return scores, -neg, jnp.take_along_axis(targ.reshape(L, -1), pos, axis=1)
+
+
+def _topk_batched_sharded(mesh, streams, weights, k, lohi, marginal,
+                          interpret):
+    """``maiz_ranking_topk_batched`` split over ``mesh`` (see there)."""
+    from jax.sharding import PartitionSpec as P
+    if lohi is None:
+        raise ValueError("a sharded sweep needs the frozen global lohi")
+    split_n = "n" in mesh.axis_names and mesh.shape["n"] > 1
+    node_spec = P("e", "n") if split_n else P("e")
+    names = [k_ for k_, v in marginal.items() if v is not None]
+    ops_ = list(streams) + [marginal[k_] for k_ in names] + [lohi, weights]
+    specs = ([node_spec] * len(streams)
+             + [P("e") if k_ == "en" else node_spec for k_ in names]
+             + [P("e"), P()])
+    n_local = streams[0].shape[1] // (mesh.shape["n"] if split_n else 1)
+
+    def local(*a):
+        mkw = dict(zip(names, a[len(streams):-2]))
+        scores, cs, ci = maiz_ranking_topk_batched(
+            *a[:len(streams)], a[-1], k=k, lohi=a[-2], interpret=interpret,
+            **mkw)
+        if split_n:
+            ci = ci + jax.lax.axis_index("n") * n_local
+        return scores, cs, ci
+
+    scores, cs, ci = jax.shard_map(
+        local, mesh=mesh, in_specs=tuple(specs),
+        out_specs=(node_spec, node_spec, node_spec), check_vma=False)(*ops_)
+    if split_n:
+        # shards are contiguous node ranges, each list (score, node)-sorted:
+        # lax.top_k's lower-position-first tie rule keeps the global order
+        neg, pos = jax.lax.top_k(-cs, min(k, streams[0].shape[1]))
+        cs, ci = -neg, jnp.take_along_axis(ci, pos, axis=1)
+    return scores, cs, ci
 
 
 def maiz_ranking_fused(ec, pue, ci_now, ci_fc, eff, sched, weights, *,

@@ -6,17 +6,11 @@ jax device state (the dry-run must set XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:                             # jax >= 0.5 names axis types explicitly
-    from jax.sharding import AxisType
 
-    def _axis_kw(n):
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:              # older jax: every mesh axis is Auto already
-    AxisType = None
-
-    def _axis_kw(n):
-        return {}
+def _axis_kw(n):
+    return {"axis_types": (AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -7,10 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # property tests skip; unit tests still run
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import ARCHS
 from repro.data.pipeline import DataConfig, PipelineState, host_batch

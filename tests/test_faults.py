@@ -23,10 +23,7 @@ import hashlib
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.faults import FaultConfig, fault_graph_key, plan_faults
 from repro.core.simulator import (SimConfig, _outage_windows, generate_jobs,

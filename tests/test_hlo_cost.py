@@ -1,11 +1,5 @@
 """The loop-aware HLO cost parser vs ground truth (subprocess: needs a
 multi-device mesh for collective tests)."""
-import pytest
-try:
-    from jax.sharding import AxisType  # noqa: F401
-except ImportError:  # pragma: no cover - older jax
-    pytest.skip("jax.sharding.AxisType unavailable in this jax",
-                allow_module_level=True)
 import os
 import subprocess
 import sys

@@ -3,10 +3,7 @@ accounting, forecasting, scenarios (the -85.68% headline), CPP projection."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # property tests skip; unit tests still run
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import carbon, cpp, forecast, telemetry
 from repro.core.ranking import RankWeights, maiz_ranking, rank_nodes

@@ -1,18 +1,10 @@
 """Multi-device behaviours (8 forced host devices) — run in SUBPROCESSES so
 the XLA device-count flag never leaks into the other tests (the brief
 requires smoke tests to see 1 device)."""
-import pytest
-try:
-    from jax.sharding import AxisType  # noqa: F401
-except ImportError:  # pragma: no cover - older jax
-    pytest.skip("jax.sharding.AxisType unavailable in this jax",
-                allow_module_level=True)
 import os
 import subprocess
 import sys
 import textwrap
-
-import pytest
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 

@@ -9,10 +9,7 @@ import hashlib
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import jax.numpy as jnp
 

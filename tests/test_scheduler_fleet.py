@@ -3,10 +3,7 @@ scenario allocation invariants (hypothesis)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # property tests skip; unit tests still run
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.fleet import synthetic_fleet
 from repro.core.scheduler import SCENARIOS, place_jobs

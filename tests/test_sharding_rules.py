@@ -3,15 +3,8 @@ devices needed)."""
 import jax
 import jax.numpy as jnp
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # property tests skip; unit tests still run
-    from _hypothesis_stub import given, settings, st
-try:
-    from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
-except ImportError:  # pragma: no cover - older jax
-    pytest.skip("jax.sharding.AxisType unavailable in this jax",
-                allow_module_level=True)
+from hypothesis import given, settings, strategies as st
+from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
 
 from repro.configs import ARCHS
 from repro.distributed.sharding import Param, Rules, resolve_spec, tree_specs

@@ -13,10 +13,7 @@ import dataclasses
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.ranking import RankWeights
 from repro.core.simulator import (SimConfig, JobSchedule, generate_jobs,
