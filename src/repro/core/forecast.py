@@ -41,6 +41,7 @@ def _design(t: jax.Array,
 
 
 @functools.partial(jax.jit, static_argnames=("horizon",))
+@jax.named_scope("forecast")
 def fit_forecast(history: jax.Array, horizon: int,
                  t0: int = 0) -> Tuple[jax.Array, jax.Array]:
     """Fit on ``history`` (T,) starting at absolute hour t0; forecast the

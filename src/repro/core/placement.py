@@ -115,6 +115,20 @@ class PlacementResult:
     scores: jax.Array     # (N,) scores at FINAL occupancy (frozen lo/hi)
     capacity: jax.Array   # (N,) free chips after all placements
     n_sweeps: jax.Array   # () int32: full O(N) decision sweeps performed
+    # (4,) int32 ``WALK_COUNTS`` of the shortlist engine; None for the
+    # full-rerank oracle, which has no shortlist
+    walk_counts: Optional[jax.Array] = None
+
+
+# What each arrival of a shortlist engine cost, by index into
+# ``walk_counts``: placed from the shortlist with no sweep, or swept
+# because (in this order of precedence) the epoch was dirty, no shortlist
+# node had room and health for the demand, or the bound outscored the best
+# feasible shortlist node.  Indices 1-3 partition ``n_sweeps`` exactly (the
+# eager first sweep counts as dirty); arrivals that are unplaceable without
+# a sweep count nowhere.
+WALK_COUNTS = ("shortlist_hit", "sweep_dirty", "sweep_no_room",
+               "sweep_bound")
 
 
 def _lo_rcp(t):
@@ -421,6 +435,7 @@ def place_lifecycle_shortlist(fleet: Fleet, demands: jax.Array,
                                      jnp.asarray(ctx["dyn_f"], jnp.float32),
                                      ctx["emb_h"], ctx["w_m"]]))
 
+        @jax.named_scope("rank_sweep")
         def sweep_topk(cap):
             ec = fleet.effective_power_kw(cap, energy=em_k) * horizon_h
             kw = dict(mkw, cap=cap.astype(jnp.float32)) if mkw else {}
@@ -429,6 +444,7 @@ def place_lifecycle_shortlist(fleet: Fleet, demands: jax.Array,
                 fleet.flops_per_j, fleet.sched_term, weights.as_array(),
                 k=k_cand, lohi=ctx["lohi"], interpret=interpret, **kw)
     else:
+        @jax.named_scope("rank_sweep")
         def sweep_topk(cap):
             scores = _ctx_scores(cap, ctx, weights)
             neg, idx = jax.lax.top_k(-scores, k_cand)
@@ -447,13 +463,13 @@ def place_lifecycle_shortlist(fleet: Fleet, demands: jax.Array,
 
     def body(e, state):
         (cap, out, sl_s, sl_i, bound_s, bound_i, cap_max, sweeps,
-         dirty) = state
+         dirty, wc) = state
         d, tgt = demands[e], nodes[e]
 
         # cond branches read the (N,) capacity but return only scalars and
         # (K,)-sized shortlist state — the lone (N,) write (the capacity
         # scatter below) covers arrivals AND releases via one signed add.
-        op = (cap, sl_s, sl_i, bound_s, bound_i, cap_max, sweeps, dirty)
+        op = (cap, sl_s, sl_i, bound_s, bound_i, cap_max, sweeps, dirty, wc)
 
         def release(op):
             """Credit -d chips to node tgt: O(1), never sweeps.
@@ -461,7 +477,7 @@ def place_lifecycle_shortlist(fleet: Fleet, demands: jax.Array,
             In-shortlist: rescore the entry (non-shortlist scores are
             untouched, the bound stays sound).  Outside: the node's score
             fell below anything the bound can certify -> dirty."""
-            cap, sl_s, sl_i, bound_s, bound_i, cap_max, sweeps, dirty = op
+            cap, sl_s, sl_i, bound_s, bound_i, cap_max, sweeps, dirty, wc = op
             new_cap = cap[tgt] - d              # d < 0: adds chips
             hitmask = (sl_i == tgt)
             hit = (~dirty) & jnp.any(hitmask)
@@ -470,15 +486,13 @@ def place_lifecycle_shortlist(fleet: Fleet, demands: jax.Array,
             return (tgt, jnp.bool_(True), sl_s, sl_i, bound_s, bound_i,
                     jnp.maximum(cap_max,
                                 jnp.where(healthy[tgt], new_cap, 0)),
-                    sweeps, dirty | (~hit))
+                    sweeps, dirty | (~hit), wc)
 
         def noop(op):
-            cap, sl_s, sl_i, bound_s, bound_i, cap_max, sweeps, dirty = op
-            return (jnp.int32(0), jnp.bool_(False), sl_s, sl_i, bound_s,
-                    bound_i, cap_max, sweeps, dirty)
+            return (jnp.int32(0), jnp.bool_(False)) + op[1:]
 
         def arrival(op):
-            cap, sl_s, sl_i, bound_s, bound_i, cap_max, sweeps, dirty = op
+            cap, sl_s, sl_i, bound_s, bound_i, cap_max, sweeps, dirty, _ = op
             # best feasible (capacity + health) shortlist entry by
             # (score, node index)
             sm = jnp.where((cap[sl_i] >= d) & healthy[sl_i], sl_s, INF)
@@ -496,18 +510,19 @@ def place_lifecycle_shortlist(fleet: Fleet, demands: jax.Array,
                                     & (~jnp.isfinite(bound_s)))
 
             def from_shortlist(op):
-                cap, sl_s, sl_i, bound_s, bound_i, cap_max, sweeps, _ = op
+                cap, sl_s, sl_i, bound_s, bound_i, cap_max, sweeps, _, wc = op
                 new_s = _one_score(cap[bnode] - d, bnode, ctx, weights)
                 return (bnode, jnp.bool_(True),
                         jnp.where(karange == kbest, new_s, sl_s), sl_i,
-                        bound_s, bound_i, cap_max, sweeps, jnp.bool_(False))
+                        bound_s, bound_i, cap_max, sweeps, jnp.bool_(False),
+                        wc)
 
             def land_from(swept, op):
                 """Place this job from a fresh sweep's (scores, top-k) and
                 open a new (clean) epoch; the landed node's shortlist entry
                 is patched in place."""
                 scores, cand_s, cand_i = swept
-                cap, _, _, _, _, _, sweeps, _ = op
+                cap, _, _, _, _, _, sweeps, _, wc = op
                 masked = jnp.where((cap >= d) & healthy, scores, INF)
                 best = jnp.argmin(masked).astype(jnp.int32)
                 ok = jnp.isfinite(masked[best])
@@ -515,8 +530,13 @@ def place_lifecycle_shortlist(fleet: Fleet, demands: jax.Array,
                 sl_s, sl_i, bound_s, bound_i = split_shortlist(cand_s,
                                                                cand_i)
                 sl_s = jnp.where(ok & (sl_i == best), new_s, sl_s)
+                # the sweep's cause, in WALK_COUNTS' order of precedence;
+                # slot 0 counts the arrivals a sweep placed until the end
+                cause = jnp.where(dirty, 1, jnp.where(feasible, 3, 2))
+                wc = tuple(c + (cause == i) + (ok & (i == 0))
+                           for i, c in enumerate(wc))
                 return (best, ok, sl_s, sl_i, bound_s, bound_i,
-                        jnp.max(hcap(cap)), sweeps + 1, jnp.bool_(False))
+                        jnp.max(hcap(cap)), sweeps + 1, jnp.bool_(False), wc)
 
             def from_sweep(op):
                 """Fresh O(N) sweep: exact placement from the full masked
@@ -531,9 +551,7 @@ def place_lifecycle_shortlist(fleet: Fleet, demands: jax.Array,
                     lambda o: land_from(sweep_topk(o[0]), o), op)
 
             def unplaceable(op):
-                cap, sl_s, sl_i, bound_s, bound_i, cap_max, sweeps, dy = op
-                return (jnp.int32(0), jnp.bool_(False), sl_s, sl_i,
-                        bound_s, bound_i, cap_max, sweeps, dy)
+                return (jnp.int32(0), jnp.bool_(False)) + op[1:]
 
             return jax.lax.cond(
                 use_sl, from_shortlist,
@@ -542,24 +560,31 @@ def place_lifecycle_shortlist(fleet: Fleet, demands: jax.Array,
 
         # flat event dispatch: sign(d) + 1 -> release | noop | arrival
         (chosen, ok, sl_s, sl_i, bound_s, bound_i, cap_max, sweeps,
-         dirty) = jax.lax.switch(
+         dirty, wc) = jax.lax.switch(
             jnp.sign(d) + 1, (release, noop, arrival), op)
         # arrivals subtract d > 0; releases subtract d < 0 (credit)
         cap = cap.at[chosen].add(jnp.where(ok, -d, 0))
         out = out.at[e].set(jnp.where(ok, chosen, -1))
         return (cap, out, sl_s, sl_i, bound_s, bound_i, cap_max, sweeps,
-                dirty)
+                dirty, wc)
 
     state = (cap0, jnp.full((E,), -1, jnp.int32),
              jnp.full((K,), INF), jnp.full((K,), N, jnp.int32),
              INF, jnp.int32(N), jnp.max(hcap(cap0)),
-             jnp.zeros((), jnp.int32), jnp.bool_(True))
+             jnp.zeros((), jnp.int32), jnp.bool_(True),
+             (jnp.zeros((), jnp.int32),) * len(WALK_COUNTS))
     out_state = jax.lax.fori_loop(
         0, E if n_events is None else n_events, body, state)
     cap, out, sweeps = out_state[0], out_state[1], out_state[7]
+    # scalar counters, touched only where a sweep runs: the per-event path
+    # carries them and does no more work
+    swept_placed, *causes = out_state[9]
+    placed = jnp.sum((demands > 0) & (out >= 0), dtype=jnp.int32)
     return PlacementResult(node=out,
                            scores=_ctx_scores(cap, ctx, weights),
-                           capacity=cap, n_sweeps=sweeps)
+                           capacity=cap, n_sweeps=sweeps,
+                           walk_counts=jnp.stack([placed - swept_placed,
+                                                  *causes]))
 
 
 # ---------------------------------------------------------------------------
@@ -595,9 +620,12 @@ def place_lifecycle_batched(fleet: Fleet, demands: jax.Array,
     is ``(L, E)`` arrival chips (pads 0), ``capacity`` the ``(L, N)``
     post-release starting capacity, ``n_events`` the ``(L,)`` compacted
     arrival counts.  Returns ``(node (L, E), capacity (L, N),
-    n_sweeps (L,))`` — **decision-identical per lane** to running the
-    sequential engine on that lane: same shortlist/bound predicates, same
-    tie-breaks, same sweep counts.
+    n_sweeps (L,), walk_counts (L, 4), sweep_rounds ())`` —
+    **decision-identical per lane** to running the sequential engine on
+    that lane: same shortlist/bound predicates, same tie-breaks, same
+    sweep and ``WALK_COUNTS`` counts.  ``sweep_rounds`` counts the outer
+    rounds below, each one batched sweep over all L lanes whether or not
+    a lane stalled; the full-rerank oracle returns None for both.
 
     Why not just ``vmap`` the sequential engine: batched ``lax.cond``
     executes BOTH branches, so every event would pay the O(N) sweep +
@@ -674,7 +702,7 @@ def place_lifecycle_batched(fleet: Fleet, demands: jax.Array,
             0, jnp.max(n_ev), fbody,
             (cap0, jnp.full((L, E), -1, jnp.int32),
              jnp.zeros((L,), jnp.int32)))
-        return out, cap, sweeps
+        return out, cap, sweeps, None, None
 
     if use_kernel:
         from repro.kernels.ops import maiz_ranking_topk_batched
@@ -698,6 +726,7 @@ def place_lifecycle_batched(fleet: Fleet, demands: jax.Array,
         else:
             mkw = {}
 
+        @jax.named_scope("rank_sweep")
         def sweep_topk(cap):
             ec = eff_pw(cap) * horizon_h
             kw = dict(mkw, cap=cap.astype(jnp.float32)) if mkw else {}
@@ -707,6 +736,7 @@ def place_lifecycle_batched(fleet: Fleet, demands: jax.Array,
                 k=k_cand, lohi=ctx["lohi"], interpret=interpret, mesh=mesh,
                 **kw)
     else:
+        @jax.named_scope("rank_sweep")
         def sweep_topk(cap):
             scores = _ctx_scores(cap, ctx, weights)
             neg, idx = jax.lax.top_k(-scores, k_cand)
@@ -726,6 +756,9 @@ def place_lifecycle_batched(fleet: Fleet, demands: jax.Array,
     # deferred scatter at the round boundary (disjoint single-node edits,
     # so the deferral is exact), keeping the per-event while carry at
     # O(L·K) + the output row instead of O(L·N).
+
+    wc_index = jnp.arange(len(WALK_COUNTS), dtype=jnp.int32)[None, :]
+    arrivals = (jnp.arange(E)[None, :] < n_ev[:, None]) & (demands > 0)
 
     def inner_cond(c):
         return jnp.any((c[3] < n_ev) & ~c[4])
@@ -768,12 +801,20 @@ def place_lifecycle_batched(fleet: Fleet, demands: jax.Array,
 
     def outer_body(st):
         (cap, out, slcap, sl_s, sl_i, bound_s, bound_i, cap_max, sweeps,
-         dirty, ptr, ptr0, need) = st
+         dirty, ptr, ptr0, need, wc, rounds) = st
         slh = jnp.take_along_axis(healthy, sl_i, 1)
         out, slcap, sl_s, ptr, need = jax.lax.while_loop(
             inner_cond, make_inner(sl_i, slh, bound_s, bound_i, cap_max,
                                    dirty),
             (out, slcap, sl_s, ptr, need))
+        # the cause of each stalled lane's sweep, counted once a round and
+        # not in the walk: a stalled lane's shortlist is as it stalled
+        p, d = ev_demand(ptr)
+        fits = jnp.isfinite(jnp.min(jnp.where(
+            (slcap >= d[:, None]) & slh, sl_s, INF), axis=1))
+        cause = jnp.where(dirty, 1, jnp.where(fits, 3, 2))
+        wc = wc + (need[:, None]
+                   & (wc_index == cause[:, None])).astype(jnp.int32)
         # apply the walk's placements (events [ptr0, ptr) that landed) to
         # the full capacity as ONE scatter of disjoint single-node edits
         seg = jnp.arange(E, dtype=jnp.int32)[None, :]
@@ -783,7 +824,6 @@ def place_lifecycle_batched(fleet: Fleet, demands: jax.Array,
         # one fresh sweep per round — the tensors ``land_from`` computes,
         # applied only on stalled lanes (at their own current capacity)
         scores, cand_s, cand_i = sweep_topk(cap)
-        p, d = ev_demand(ptr)
         masked = jnp.where((cap >= d[:, None]) & healthy, scores, INF)
         best = jnp.argmin(masked, axis=1).astype(jnp.int32)
         ok = jnp.isfinite(
@@ -806,7 +846,8 @@ def place_lifecycle_batched(fleet: Fleet, demands: jax.Array,
                 pick(bound_s2, bound_s), pick(bound_i2, bound_i),
                 pick(cm2, cap_max), sweeps + need.astype(jnp.int32),
                 dirty & ~need, ptr, ptr,
-                jnp.zeros_like(need))
+                jnp.zeros_like(need),
+                wc.at[:, 0].add((need & ok).astype(jnp.int32)), rounds + 1)
 
     st = (cap0, jnp.full((L, E), -1, jnp.int32),
           jnp.take_along_axis(cap0, jnp.full((L, K), N - 1, jnp.int32), 1),
@@ -814,6 +855,12 @@ def place_lifecycle_batched(fleet: Fleet, demands: jax.Array,
           jnp.full((L,), INF), jnp.full((L,), N, jnp.int32),
           hmax(cap0), jnp.zeros((L,), jnp.int32),
           jnp.ones((L,), bool), jnp.zeros((L,), jnp.int32),
-          jnp.zeros((L,), jnp.int32), jnp.zeros((L,), bool))
+          jnp.zeros((L,), jnp.int32), jnp.zeros((L,), bool),
+          jnp.zeros((L, len(WALK_COUNTS)), jnp.int32),
+          jnp.zeros((), jnp.int32))
     st = jax.lax.while_loop(outer_cond, outer_body, st)
-    return st[1], st[0], st[8]
+    # column 0 counted the arrivals a sweep placed: those the shortlist
+    # placed are the rest of the placed arrivals
+    placed = jnp.sum(arrivals & (st[1] >= 0), axis=1, dtype=jnp.int32)
+    wc = st[13].at[:, 0].set(placed - st[13][:, 0])
+    return st[1], st[0], st[8], wc, st[14]

@@ -97,6 +97,9 @@ class Placement:
     node: jax.Array      # (J,) chosen node per job, -1 = unplaceable
     scores: jax.Array    # (N,) rank scores at FINAL occupancy (frozen lo/hi)
     n_sweeps: Optional[jax.Array] = None   # () int32 full rank sweeps
+    # (4,) int32 per-arrival outcome counts of the shortlist engine
+    # (``placement.WALK_COUNTS``); None for the full-rerank oracle
+    walk_counts: Optional[jax.Array] = None
 
 
 # Above this N/J the full re-rank's O(J·N) rescore traffic outweighs the
@@ -172,7 +175,8 @@ def place_jobs(fleet: Fleet, demands: jax.Array,
                                              horizon_h, energy=energy)
     else:
         raise ValueError(f"unknown placement engine: {engine!r}")
-    return Placement(node=r.node, scores=r.scores, n_sweeps=r.n_sweeps)
+    return Placement(node=r.node, scores=r.scores, n_sweeps=r.n_sweeps,
+                     walk_counts=r.walk_counts)
 
 
 place_jobs_jit = jax.jit(place_jobs,
@@ -213,18 +217,21 @@ def place_events(fleet: Fleet, demands: jax.Array, nodes: jax.Array,
     ``_auto_engine`` — bit-identical placements either way."""
     if engine == "auto":
         engine = _auto_engine(fleet.n, demands.shape[0], use_kernel)
-    if engine == "shortlist":
-        r = placement.place_lifecycle_shortlist(
-            fleet, demands, nodes, weights, horizon_h, shortlist=shortlist,
-            use_kernel=use_kernel, interpret=interpret, capacity=capacity,
-            n_events=n_events, eager_sweep=eager_sweep, energy=energy)
-    elif engine == "full":
-        r = placement.place_lifecycle_full_rerank(
-            fleet, demands, nodes, weights, horizon_h, capacity=capacity,
-            n_events=n_events, energy=energy)
-    else:
+    if engine not in ("shortlist", "full"):
         raise ValueError(f"unknown placement engine: {engine!r}")
-    return Placement(node=r.node, scores=r.scores, n_sweeps=r.n_sweeps)
+    with jax.named_scope("placement_walk"):
+        if engine == "shortlist":
+            r = placement.place_lifecycle_shortlist(
+                fleet, demands, nodes, weights, horizon_h,
+                shortlist=shortlist, use_kernel=use_kernel,
+                interpret=interpret, capacity=capacity, n_events=n_events,
+                eager_sweep=eager_sweep, energy=energy)
+        else:
+            r = placement.place_lifecycle_full_rerank(
+                fleet, demands, nodes, weights, horizon_h,
+                capacity=capacity, n_events=n_events, energy=energy)
+    return Placement(node=r.node, scores=r.scores, n_sweeps=r.n_sweeps,
+                     walk_counts=r.walk_counts)
 
 
 place_events_jit = jax.jit(place_events,

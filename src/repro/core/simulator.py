@@ -55,6 +55,15 @@ policy expression with the host path so placements and counters match the
 oracle exactly (emissions to f32 tolerance; year-scale runs go from
 minutes to seconds — see EXPERIMENTS.md §Scanned core and BENCH_sim.json's
 ``long_run``).
+
+**Instrumentation.**  The compiled drivers open host spans
+(``jax.profiler.TraceAnnotation``: ``plan_build``, ``dispatch``,
+``device_wait``, ``readback``, ``result_assembly``), which cost nothing
+unless a profiler trace is active, and name their device layers with
+``jax.named_scope`` (``epoch_pre``, ``placement_walk``, ``rank_sweep``,
+``epoch_post``, ``router``; ``forecast`` in ``forecast.fit_forecast``),
+which changes only HLO metadata.  ``SimResult.walk_counts`` and
+``sweep_rounds`` are always counted, like ``rank_sweeps``.
 """
 from __future__ import annotations
 
@@ -299,6 +308,13 @@ class SimResult:
     # (n_tenants + 1,) request gCO2 per tenant (spare last bin stays 0);
     # bins sum exactly to req_gco2
     tenant_request_g: Optional[np.ndarray] = None
+    # the compiled drivers' shortlist-engine arrival outcomes, summed over
+    # epochs (placement.WALK_COUNTS; indices 1-3 sum to rank_sweeps); None
+    # for the full-rerank engine and the host loop
+    walk_counts: Optional[Tuple[int, int, int, int]] = None
+    # batched sweep launches (over all lanes) of this lane's ensemble
+    # bucket; None outside the batched ensemble
+    sweep_rounds: Optional[int] = None
 
 
 # ---------------------------------------------------------------------------
@@ -317,7 +333,8 @@ def _place_epoch(pue, power_kw, chips_total, straggler, flops_per_j,
     core pre-applies an epoch's leading releases as one scatter (they are
     commutative capacity edits on a dirty engine) and passes the
     post-release capacity as ``cap_start`` — identical final state, fewer
-    loop iterations."""
+    loop iterations.  Returns ``(node, capacity, n_sweeps, walk_counts)``
+    (``walk_counts`` None for the full-rerank engine)."""
     engine, shortlist, use_kernel, weights = statics[:4]
     interpret = statics[9]
     fleet = Fleet(ci_now=ci_now.astype(jnp.float32),
@@ -325,20 +342,18 @@ def _place_epoch(pue, power_kw, chips_total, straggler, flops_per_j,
                   pue=pue, power_kw=power_kw, capacity=cap_ctx,
                   healthy=healthy, straggler_score=straggler,
                   flops_per_j=flops_per_j, chips_total=chips_total)
-    if engine == "full":
-        r = place_lifecycle_full_rerank(fleet, demands, nodes, weights,
-                                        horizon_h=1.0, capacity=cap_start,
-                                        n_events=n_events, energy=energy)
-    else:
-        r = place_lifecycle_shortlist(fleet, demands, nodes, weights,
-                                      horizon_h=1.0, shortlist=shortlist,
-                                      use_kernel=use_kernel,
-                                      interpret=interpret,
-                                      capacity=cap_start,
-                                      n_events=n_events,
-                                      eager_sweep=eager_sweep,
-                                      energy=energy)
-    return r.node, r.capacity, r.n_sweeps
+    with jax.named_scope("placement_walk"):
+        if engine == "full":
+            r = place_lifecycle_full_rerank(
+                fleet, demands, nodes, weights, horizon_h=1.0,
+                capacity=cap_start, n_events=n_events, energy=energy)
+        else:
+            r = place_lifecycle_shortlist(
+                fleet, demands, nodes, weights, horizon_h=1.0,
+                shortlist=shortlist, use_kernel=use_kernel,
+                interpret=interpret, capacity=cap_start, n_events=n_events,
+                eager_sweep=eager_sweep, energy=energy)
+    return r.node, r.capacity, r.n_sweeps, r.walk_counts
 
 
 def _epoch_core(traces, ridx, pue, power_kw, chips_total, straggler,
@@ -385,7 +400,7 @@ def _epoch_core(traces, ridx, pue, power_kw, chips_total, straggler,
     else:
         ci_fc = ci_now
         fut_rate = jnp.float32(jnp.inf)
-    node, cap_out, n_sweeps = _place_epoch(
+    node, cap_out, n_sweeps, _ = _place_epoch(
         pue, power_kw, chips_total, straggler, flops_per_j, ci_now, ci_fc,
         cap, cap, healthy, demands, nodes, statics, energy=energy)
     cur_rate = jnp.min(jnp.where(healthy, ci_now * pue, jnp.inf))
@@ -1143,6 +1158,7 @@ def _traj_scan(arrs, statics, dims, ensemble: bool, mesh=None):
                 xs["gw_min"] = gw_min                             # (T,)
         return xs
 
+    @jax.named_scope("epoch_pre")
     def epoch_pre(arrs, carry, x):
         """Epoch parts 1-3: EOL releases, evictions + migration policy,
         and the compacted arrival-event stream — everything the placement
@@ -1343,10 +1359,13 @@ def _traj_scan(arrs, statics, dims, ensemble: bool, mesh=None):
             mid.update(mig_until=mig_until, mig_nfail=mig_nfail)
         return mid
 
-    def epoch_post(arrs, mid, out_c, cap2, n_sw):
+    @jax.named_scope("epoch_post")
+    def epoch_post(arrs, mid, out_c, cap2, n_sw, walk_counts, rounds):
         """Epoch parts 4-5: scatter the compacted placements back, record
         mover/arrival outcomes, run the deferral queue admission, and
-        account emissions — returns the scan (carry, ys)."""
+        account emissions — returns the scan (carry, ys).  The engine's
+        ``walk_counts`` and ``rounds`` (None where the engine has none)
+        pass through to ys."""
         pue, power_kw = arrs["pue"], arrs["power_kw"]
         chips_total = arrs["chips_total"]
         dur_d, arrive_d = arrs["duration"], arrs["arrive"]
@@ -1502,45 +1521,46 @@ def _traj_scan(arrs, statics, dims, ensemble: bool, mesh=None):
             ten_t = jnp.zeros((1,), jnp.float32)
 
         if n_svc > 0:
-            # ---- 5b. request routing + serving attribution -----------
-            # lanes are the POST-update slot tables (the host routes over
-            # the end-of-epoch active set); the routing DECISION reads
-            # the observed CI column — mid["ci_col"] is degraded under
-            # faults, exactly like every placement decision above — and
-            # the request-carbon ATTRIBUTION reads ground truth.  All
-            # arithmetic inside route_epoch is int32 except two pinned
-            # f32 ops, so routed/offered match the host loop bit-exactly
-            # (see repro.core.router).
-            occ_r = slot_jid >= 0
-            r_jid = jnp.maximum(slot_jid, 0)
-            svc_l = jnp.where(occ_r, arrs["svc"][r_jid], -1)
-            w_l = jnp.where(occ_r, arrs["qweight"][r_jid], 0)
-            chips_l = jnp.where(occ_r, arrs["chips"][r_jid], 0)
-            cap_l = jnp.where(
-                occ_r, arrs["lam_cap"][jnp.clip(chips_l, 0, chips_max)],
-                0)
-            node_l = jnp.clip(slot_node, 0, N - 1)
-            carbon_l = pue[node_l] * mid["ci_col"][node_l]
-            routed, offered = routerlib.route_epoch(
-                jnp, req_t=mid["req_t"], svc=svc_l, jid=slot_jid,
-                weight=w_l, cap=cap_l, carbon=carbon_l, n_svc=n_svc,
-                greenness=arrs["greenness"])
-            served_t = jnp.sum(routed)
-            offered_t = jnp.sum(offered[:n_svc])
-            viol_t = jnp.sum(((routed > cap_l)
-                              & (svc_l >= 0)).astype(jnp.int32))
-            g_lane = routed.astype(jnp.float32) * (
-                arrs["en_reqkwh"] * (pue[node_l] * mid["ci_true"][node_l]))
-            reqg_t = jnp.sum(g_lane)
-            p99_l = routerlib.modeled_p99(jnp, routed, chips_l,
-                                          chips_max, arrs["tr_mu"])
-            p99w_t = jnp.sum(routed.astype(jnp.float32) * p99_l)
-            if n_ten > 0:
-                tenreq_t = jnp.zeros((n_ten + 1,), jnp.float32).at[
-                    jnp.where(occ_r, arrs["tenant"][r_jid], n_ten)].add(
-                    g_lane, mode="drop")
-            else:
-                tenreq_t = jnp.zeros((1,), jnp.float32)
+            with jax.named_scope("router"):
+                # ---- 5b. request routing + serving attribution -----------
+                # lanes are the POST-update slot tables (the host routes over
+                # the end-of-epoch active set); the routing DECISION reads
+                # the observed CI column — mid["ci_col"] is degraded under
+                # faults, exactly like every placement decision above — and
+                # the request-carbon ATTRIBUTION reads ground truth.  All
+                # arithmetic inside route_epoch is int32 except two pinned
+                # f32 ops, so routed/offered match the host loop bit-exactly
+                # (see repro.core.router).
+                occ_r = slot_jid >= 0
+                r_jid = jnp.maximum(slot_jid, 0)
+                svc_l = jnp.where(occ_r, arrs["svc"][r_jid], -1)
+                w_l = jnp.where(occ_r, arrs["qweight"][r_jid], 0)
+                chips_l = jnp.where(occ_r, arrs["chips"][r_jid], 0)
+                cap_l = jnp.where(
+                    occ_r, arrs["lam_cap"][jnp.clip(chips_l, 0, chips_max)],
+                    0)
+                node_l = jnp.clip(slot_node, 0, N - 1)
+                carbon_l = pue[node_l] * mid["ci_col"][node_l]
+                routed, offered = routerlib.route_epoch(
+                    jnp, req_t=mid["req_t"], svc=svc_l, jid=slot_jid,
+                    weight=w_l, cap=cap_l, carbon=carbon_l, n_svc=n_svc,
+                    greenness=arrs["greenness"])
+                served_t = jnp.sum(routed)
+                offered_t = jnp.sum(offered[:n_svc])
+                viol_t = jnp.sum(((routed > cap_l)
+                                  & (svc_l >= 0)).astype(jnp.int32))
+                g_lane = routed.astype(jnp.float32) * (
+                    arrs["en_reqkwh"] * (pue[node_l] * mid["ci_true"][node_l]))
+                reqg_t = jnp.sum(g_lane)
+                p99_l = routerlib.modeled_p99(jnp, routed, chips_l,
+                                              chips_max, arrs["tr_mu"])
+                p99w_t = jnp.sum(routed.astype(jnp.float32) * p99_l)
+                if n_ten > 0:
+                    tenreq_t = jnp.zeros((n_ten + 1,), jnp.float32).at[
+                        jnp.where(occ_r, arrs["tenant"][r_jid], n_ten)].add(
+                        g_lane, mode="drop")
+                else:
+                    tenreq_t = jnp.zeros((1,), jnp.float32)
 
         carry = (cap2, njobs, slot_jid, slot_node, slot_end, defer_ids,
                  mid["mig_cost"] + mid["mig_cost_t"], overflow)
@@ -1554,7 +1574,7 @@ def _traj_scan(arrs, statics, dims, ensemble: bool, mesh=None):
               mov_jid, ys_mov_node,
               jnp.where(place_new, narr_jid, -1),
               jnp.where(place_new, nnode, -1),
-              overflow, mid["failed_t"], ten_t)
+              overflow, mid["failed_t"], ten_t, walk_counts, rounds)
         if n_svc > 0:
             ys = ys + (served_t, offered_t, viol_t, reqg_t, p99w_t,
                        tenreq_t)
@@ -1575,13 +1595,13 @@ def _traj_scan(arrs, statics, dims, ensemble: bool, mesh=None):
         def body(carry, x):
             mid = epoch_pre(arrs, carry, x)
             tgt = jnp.full((EV,), -1, jnp.int32)
-            out_c, cap2, n_sw = _place_epoch(
+            out_c, cap2, n_sw, wc = _place_epoch(
                 arrs["pue"], arrs["power_kw"], arrs["chips_total"],
                 mid["strag"], arrs["flops_per_j"], mid["ci_col"],
                 mid["ci_fc"], mid["cap_ctx"], mid["cap_start"],
                 mid["healthy"], mid["dem"], tgt, statics,
                 n_events=mid["n_ev"], eager_sweep=True, energy=em_tr)
-            return epoch_post(arrs, mid, out_c, cap2, n_sw)
+            return epoch_post(arrs, mid, out_c, cap2, n_sw, wc, None)
 
         init = (arrs["capacity"], jnp.zeros((N,), jnp.int32),
                 jnp.full((S,), -1, jnp.int32), jnp.zeros((S,), jnp.int32),
@@ -1610,12 +1630,15 @@ def _traj_scan(arrs, statics, dims, ensemble: bool, mesh=None):
                       straggler_score=mid["strag"],
                       flops_per_j=arrs["flops_per_j"],
                       chips_total=arrs["chips_total"])
-        out_c, cap2, n_sw = place_lifecycle_batched(
-            fleet, mid["dem"], weights, horizon_h=1.0, engine=engine,
-            shortlist=shortlist, use_kernel=use_kernel, interpret=interpret,
-            capacity=mid["cap_start"], n_events=mid["n_ev"],
-            energy=em_tr, mesh=mesh)
-        return vpost(arrs, mid, out_c, cap2, n_sw)
+        with jax.named_scope("placement_walk"):
+            out_c, cap2, n_sw, wc, rounds = place_lifecycle_batched(
+                fleet, mid["dem"], weights, horizon_h=1.0, engine=engine,
+                shortlist=shortlist, use_kernel=use_kernel,
+                interpret=interpret, capacity=mid["cap_start"],
+                n_events=mid["n_ev"], energy=em_tr, mesh=mesh)
+        if rounds is not None:      # one count for the bucket, per lane
+            rounds = jnp.broadcast_to(rounds, (L,))
+        return vpost(arrs, mid, out_c, cap2, n_sw, wc, rounds)
 
     init = (arrs["capacity"], jnp.zeros((L, N), jnp.int32),
             jnp.full((L, S), -1, jnp.int32), jnp.zeros((L, S), jnp.int32),
@@ -1876,7 +1899,7 @@ def _scan_result(run: _ScanRun, carry, ys) -> SimResult:
     host (numpy inputs; the ensemble slices its member lane first)."""
     jobs, plan, T, J = run.jobs, run.plan, run.cfg.epochs, run.jobs.n
     defer_f, mig_cost_f, overflow_f = carry[5], carry[6], carry[7]
-    ys = [np.asarray(y) for y in ys]
+    ys = [_host(y) for y in ys]
     (e_t, n_sw, completed_t, dropped_t, placed_t, deferred_t, mig_t,
      evi_t, miss_t, mov_jid, mov_node, new_jid, new_node, ov_t,
      failed_t, ten_t) = ys[:16]
@@ -1929,9 +1952,10 @@ def _scan_result(run: _ScanRun, carry, ys) -> SimResult:
         # structurally zero, and the idle/remainder bin sits last
         tg = ten_t.astype(np.float64).sum(axis=0)
         tenant_g = np.concatenate([tg[:n_run], tg[-1:]])
+    wc_t, rounds_t = ys[16:18]
     req_kw = {}
-    if len(ys) > 16:
-        served_t, offered_t, viol_t, reqg_t, p99w_t, tenreq_t = ys[16:22]
+    if len(ys) > 18:
+        served_t, offered_t, viol_t, reqg_t, p99w_t, tenreq_t = ys[18:24]
         served = int(served_t.astype(np.int64).sum())
         req_kw = dict(
             req_served=served,
@@ -1963,7 +1987,17 @@ def _scan_result(run: _ScanRun, carry, ys) -> SimResult:
         safe_epochs=int(run.fplan.safe.sum())
         if run.fplan is not None else 0,
         start_epoch=start_epoch,
-        tenant_emissions_g=tenant_g, **req_kw)
+        tenant_emissions_g=tenant_g,
+        walk_counts=None if wc_t is None
+        else tuple(int(c) for c in wc_t.astype(np.int64).sum(axis=0)),
+        sweep_rounds=None if rounds_t is None
+        else int(rounds_t.astype(np.int64).sum()), **req_kw)
+
+
+def _host(x):
+    """A device output on the host; None (a counter the engine lacks)
+    stays None."""
+    return None if x is None else np.asarray(x)
 
 
 def simulate_fleet_scan(fleet0: Fleet, region_ci: np.ndarray,
@@ -1995,13 +2029,31 @@ def simulate_fleet_scan(fleet0: Fleet, region_ci: np.ndarray,
     ``pad_plan`` buckets every static buffer (and the job-table width) to
     ``_pad_bucket`` sizes — behavior-neutral, but seed ensembles with
     slightly different schedules then share one compiled trajectory."""
-    run = _prepare_scan_run(fleet0, region_ci, ridx, cfg, jobs, pad_plan)
-    dims, jp, nmax = _shared_dims([run], pad_plan)
-    arrs = _build_arrs(run, dims, jp, nmax)
-    carry, ys = jax.block_until_ready(
-        _scan_trajectory(arrs, run.statics, dims))
-    return _scan_result(run, [np.asarray(c) for c in carry],
-                        [np.asarray(y) for y in ys])
+    run, dims, arrs = _scan_inputs(fleet0, region_ci, ridx, cfg, jobs,
+                                   pad_plan)
+    with _span("dispatch"):
+        out = _scan_trajectory(arrs, run.statics, dims)
+    with _span("device_wait"):
+        carry, ys = jax.block_until_ready(out)
+    with _span("readback"):
+        carry = [np.asarray(c) for c in carry]
+        ys = [_host(y) for y in ys]
+    with _span("result_assembly"):
+        return _scan_result(run, carry, ys)
+
+
+def _span(name: str):
+    """A host span on the profiler's clock; free unless a trace is on."""
+    return jax.profiler.TraceAnnotation(name)
+
+
+def _scan_inputs(fleet0, region_ci, ridx, cfg, jobs, pad_plan):
+    """Plan build of one scanned trajectory: ``(run, dims, arrs)``."""
+    with _span("plan_build"):
+        run = _prepare_scan_run(fleet0, region_ci, ridx, cfg, jobs,
+                                pad_plan)
+        dims, jp, nmax = _shared_dims([run], pad_plan)
+        return run, dims, _build_arrs(run, dims, jp, nmax)
 
 
 _PARITY_COUNTERS = ("rank_sweeps", "arrivals_placed", "jobs_completed",
@@ -2068,40 +2120,86 @@ def simulate_fleet_ensemble(runs, *, pad_plan: bool = True,
     TPU).  XLA cannot partition a compiled Pallas kernel, so on a sharded
     ensemble the sweep runs per device under ``shard_map`` over the same
     mesh (``ops.maiz_ranking_topk_batched``)."""
-    preps = []
-    for spec in runs:
-        jobs = spec[4] if len(spec) > 4 else None
-        preps.append(_prepare_scan_run(spec[0], spec[1], spec[2], spec[3],
-                                       jobs, pad_plan))
-    buckets: Dict[tuple, list] = {}
-    for i, p in enumerate(preps):
-        buckets.setdefault(_bucket_key(p), []).append(i)
-    results: list = [None] * len(preps)
-    for idxs in buckets.values():
-        members = [preps[i] for i in idxs]
-        dims, jp, nmax = _shared_dims(members, pad_plan)
-        built = [_build_arrs(m, dims, jp, nmax) for m in members]
-        stacked = {k: jnp.stack([b[k] for b in built]) for k in built[0]}
-        del built
-        mesh = None
-        if shard:
-            stacked, mesh = _shard_over_e(
-                stacked, axes="en" if shard == "en" else "e")
+    runs = list(runs)
+    results: list = [None] * len(runs)
+    for idxs, members, dims, stacked, mesh in _ensemble_buckets(
+            runs, pad_plan, shard):
         with warnings.catch_warnings():
             # input donation is best-effort: only the lanes that alias a
             # scan carry are consumed, the rest warn — expected, not a bug
             warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable")
-            carry, ys = jax.block_until_ready(
-                _ensemble_trajectory(stacked, members[0].statics, dims,
-                                     mesh=mesh))
-        carry = [np.asarray(c) for c in carry]
-        ys = [np.asarray(y) for y in ys]
-        for lane, i in enumerate(idxs):
-            results[i] = _scan_result(preps[i],
-                                      [c[lane] for c in carry],
-                                      [y[lane] for y in ys])
+            with _span("dispatch"):
+                out = _ensemble_trajectory(stacked, members[0].statics,
+                                           dims, mesh=mesh)
+        with _span("device_wait"):
+            carry, ys = jax.block_until_ready(out)
+        with _span("readback"):
+            carry = [np.asarray(c) for c in carry]
+            ys = [_host(y) for y in ys]
+        with _span("result_assembly"):
+            for lane, (i, m) in enumerate(zip(idxs, members)):
+                results[i] = _scan_result(
+                    m, [c[lane] for c in carry],
+                    [None if y is None else y[lane] for y in ys])
     return results
+
+
+def _ensemble_buckets(runs, pad_plan: bool, shard):
+    """Plan build of an ensemble, one graph bucket at a time: yields
+    ``(member indices, prepared members, dims, stacked inputs, mesh)``."""
+    with _span("plan_build"):
+        preps = []
+        for spec in runs:
+            jobs = spec[4] if len(spec) > 4 else None
+            preps.append(_prepare_scan_run(spec[0], spec[1], spec[2],
+                                           spec[3], jobs, pad_plan))
+        buckets: Dict[tuple, list] = {}
+        for i, p in enumerate(preps):
+            buckets.setdefault(_bucket_key(p), []).append(i)
+    for idxs in buckets.values():
+        with _span("plan_build"):
+            members = [preps[i] for i in idxs]
+            dims, jp, nmax = _shared_dims(members, pad_plan)
+            built = [_build_arrs(m, dims, jp, nmax) for m in members]
+            stacked = {k: jnp.stack([b[k] for b in built])
+                       for k in built[0]}
+            del built
+            mesh = None
+            if shard:
+                stacked, mesh = _shard_over_e(
+                    stacked, axes="en" if shard == "en" else "e")
+        yield idxs, members, dims, stacked, mesh
+
+
+def program_texts(runs, *, ensemble: bool, pad_plan: bool,
+                  shard=False) -> list:
+    """Optimized HLO text of each distinct program that
+    ``simulate_fleet_ensemble(runs, pad_plan=..., shard=...)``
+    (``ensemble=True``) or ``simulate_fleet_scan(*run, pad_plan=...)`` for
+    each run (``ensemble=False``) dispatches, built by the same plan-build
+    code and compiled through the same compilation cache (a hit where the
+    process has run them).  Nothing runs.  Each instruction's
+    ``op_name`` metadata carries the named scopes of the module
+    docstring, so a profiler trace's operations can be mapped to them."""
+    texts = []
+
+    def add(fn, *args, **kw):
+        text = fn.lower(*args, **kw).compile().as_text()
+        if text not in texts:
+            texts.append(text)
+
+    if ensemble:
+        for _, members, dims, stacked, mesh in _ensemble_buckets(
+                list(runs), pad_plan, shard):
+            add(_ensemble_trajectory, stacked, members[0].statics, dims,
+                mesh=mesh)
+    else:
+        for spec in runs:
+            run, dims, arrs = _scan_inputs(
+                *spec[:4], spec[4] if len(spec) > 4 else None, pad_plan)
+            add(_scan_trajectory, arrs, run.statics, dims)
+    return texts
 
 
 # the stacked buffers that carry the node axis in dim 1 — the only ones a
