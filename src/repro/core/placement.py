@@ -21,6 +21,26 @@ instead:
    bound lexicographically, it IS the global argmin and the O(K) pick is
    exact.
 
+   **Room rule.**  The sweep ranks only the *eligible* nodes,
+   ``where(healthy, cap, 0) >= dmin`` with ``dmin`` the smallest positive
+   demand among the call's arrivals; the others score +inf for the top-k
+   alone (the placing argmin keeps its own ``(cap >= d) & healthy`` mask
+   over the full scores, where an ineligible node, ``hcap < dmin <= d``,
+   never wins either).  Without it a fleet whose best-scoring nodes are
+   full sweeps once per arrival: every shortlist entry lacks room.  It is
+   exact.  Every arrival has ``d >= dmin``, so an ineligible node cannot
+   host any of them until its capacity rises; within an epoch that takes
+   a release, which marks the epoch dirty outside the shortlist and is
+   rescored in O(1) on it.  So "best feasible shortlist entry beats the
+   bound" still certifies the global masked argmin.  With fewer than K+1
+   eligible nodes the bound is +inf and the shortlist holds every
+   eligible node, so a clean epoch with no feasible entry still means
+   unplaceable; the +inf filler entries get the index N, which no release
+   or arrival can take.  Health is static within a call, so an unhealthy
+   node is ineligible throughout.  The rule reads only the call's
+   capacities and demands: where every node has room for every demand,
+   every node is eligible and the sweep is the unmasked one.
+
 3. **Fallback sweeps.**  When the bound is violated — shortlist capacity
    exhausted for this demand, or every surviving entry outscored by the
    bound — the engine runs a fresh full sweep, places the current job from
@@ -251,6 +271,33 @@ def _one_score(cap_b, b, ctx, w: RankWeights):
     return _ctx_scores(cap_b[None], g, w)[0]
 
 
+def _smallest_arrival(demands, live, axis=None):
+    """The call's smallest arrival demand (per lane along ``axis``): the
+    room a node needs to host any of them.  With no arrival, the dtype's
+    largest value, so that no node qualifies and nothing is swept."""
+    big = jnp.iinfo(demands.dtype).max
+    return jnp.min(jnp.where(live, demands, big), axis=axis)
+
+
+def _room_kwargs(mkw, hcap, dmin):
+    """A kernel sweep's keyword arguments: the marginal streams and the
+    room threshold.  With the marginal streams the health-masked ``cap``
+    stream is the room (an unhealthy node's marginal term then counts it
+    busy, which no caller sees: the node scores +inf); without them the
+    room is a stream of its own."""
+    room = hcap.astype(jnp.float32)
+    if mkw:
+        return dict(mkw, cap=room, room_min=dmin)
+    return dict(room=room, room_min=dmin)
+
+
+def _no_node_past_room(cand_s, cand_i, n):
+    """Candidates past the last node with room score +inf; give them the
+    index ``n``, which names no node, as an empty shortlist does: no
+    release can rescore them and no arrival can take them."""
+    return jnp.where(jnp.isfinite(cand_s), cand_i, n)
+
+
 def place_jobs_full_rerank(fleet: Fleet, demands: jax.Array,
                            weights: RankWeights = RankWeights(),
                            horizon_h: float = 1.0,
@@ -370,6 +417,11 @@ def place_lifecycle_shortlist(fleet: Fleet, demands: jax.Array,
     float32 tolerance (not bitwise; exact-parity guarantees are for the
     default jnp scoring).
 
+    Each sweep ranks only nodes whose healthy free chips reach the
+    call's smallest arrival (``dmin``, over the first ``n_events``
+    events); the module docstring, point 2, says why that stays exact.
+    The eager sweep follows the same rule.
+
     The engine starts *dirty* (no shortlist yet): leading releases are pure
     O(1) capacity edits and the first arrival performs the epoch's lazy
     initial sweep.  Releases on shortlist nodes are rescored in O(1);
@@ -410,6 +462,12 @@ def place_lifecycle_shortlist(fleet: Fleet, demands: jax.Array,
     # static per call, so it composes with the bound argument unchanged
     healthy = fleet.healthy
     hcap = lambda cap: jnp.where(healthy, cap, 0)
+    # the room rule (module docstring, point 2): candidates are the nodes
+    # whose healthy free chips reach the call's smallest arrival
+    live = demands > 0
+    if n_events is not None:
+        live = live & (jnp.arange(E) < n_events)
+    dmin = _smallest_arrival(demands, live)
 
     # One epoch sweep = scores + the top-(K+1) candidate list in (score,
     # node index) lexicographic order: the kernel path gets it from the
@@ -438,7 +496,7 @@ def place_lifecycle_shortlist(fleet: Fleet, demands: jax.Array,
         @jax.named_scope("rank_sweep")
         def sweep_topk(cap):
             ec = fleet.effective_power_kw(cap, energy=em_k) * horizon_h
-            kw = dict(mkw, cap=cap.astype(jnp.float32)) if mkw else {}
+            kw = _room_kwargs(mkw, hcap(cap), dmin)
             return maiz_ranking_topk(
                 ec, fleet.pue, fleet.ci_now, fleet.ci_forecast,
                 fleet.flops_per_j, fleet.sched_term, weights.as_array(),
@@ -447,10 +505,12 @@ def place_lifecycle_shortlist(fleet: Fleet, demands: jax.Array,
         @jax.named_scope("rank_sweep")
         def sweep_topk(cap):
             scores = _ctx_scores(cap, ctx, weights)
-            neg, idx = jax.lax.top_k(-scores, k_cand)
+            ranked = jnp.where(hcap(cap) >= dmin, scores, INF)
+            neg, idx = jax.lax.top_k(-ranked, k_cand)
             return scores, -neg, idx.astype(jnp.int32)
 
     def split_shortlist(cand_s, cand_i):
+        cand_i = _no_node_past_room(cand_s, cand_i, N)
         if full_cover:
             return cand_s[:K], cand_i[:K], INF, jnp.int32(N)
         return cand_s[:K], cand_i[:K], cand_s[K], cand_i[K]
@@ -614,7 +674,9 @@ def place_lifecycle_batched(fleet: Fleet, demands: jax.Array,
                             mesh: Optional[jax.sharding.Mesh] = None):
     """Arrival-only lifecycle placement over an explicit leading lane axis
     — the batched-ensemble twin of ``place_lifecycle_shortlist`` (with
-    ``eager_sweep``) and ``place_lifecycle_full_rerank``.
+    ``eager_sweep``) and ``place_lifecycle_full_rerank``.  Its sweeps
+    follow the same room rule (module docstring, point 2), ``dmin`` taken
+    per lane over that lane's ``n_events`` arrivals.
 
     ``fleet`` carries ``(L, N)`` leaves (L ensemble lanes), ``demands``
     is ``(L, E)`` arrival chips (pads 0), ``capacity`` the ``(L, N)``
@@ -673,7 +735,11 @@ def place_lifecycle_batched(fleet: Fleet, demands: jax.Array,
     cap0 = fleet.capacity if capacity is None else capacity
     healthy = fleet.healthy
     n_ev = jnp.full((L,), E, jnp.int32) if n_events is None else n_events
-    hmax = lambda cap: jnp.max(jnp.where(healthy, cap, 0), axis=1)
+    hcap = lambda cap: jnp.where(healthy, cap, 0)
+    hmax = lambda cap: jnp.max(hcap(cap), axis=1)
+    arrivals = (jnp.arange(E)[None, :] < n_ev[:, None]) & (demands > 0)
+    # the sequential engine's room rule, one smallest arrival per lane
+    dmin = _smallest_arrival(demands, arrivals, axis=1)
 
     def ev_demand(ptr):
         p = jnp.minimum(ptr, E - 1)
@@ -729,7 +795,7 @@ def place_lifecycle_batched(fleet: Fleet, demands: jax.Array,
         @jax.named_scope("rank_sweep")
         def sweep_topk(cap):
             ec = eff_pw(cap) * horizon_h
-            kw = dict(mkw, cap=cap.astype(jnp.float32)) if mkw else {}
+            kw = _room_kwargs(mkw, hcap(cap), dmin)
             return maiz_ranking_topk_batched(
                 ec, fleet.pue, fleet.ci_now, fleet.ci_forecast,
                 fleet.flops_per_j, fleet.sched_term, weights.as_array(),
@@ -739,10 +805,12 @@ def place_lifecycle_batched(fleet: Fleet, demands: jax.Array,
         @jax.named_scope("rank_sweep")
         def sweep_topk(cap):
             scores = _ctx_scores(cap, ctx, weights)
-            neg, idx = jax.lax.top_k(-scores, k_cand)
+            ranked = jnp.where(hcap(cap) >= dmin[:, None], scores, INF)
+            neg, idx = jax.lax.top_k(-ranked, k_cand)
             return scores, -neg, idx.astype(jnp.int32)
 
     def split_shortlist(cand_s, cand_i):
+        cand_i = _no_node_past_room(cand_s, cand_i, N)
         if full_cover:
             return (cand_s[:, :K], cand_i[:, :K],
                     jnp.full((L,), INF), jnp.full((L,), N, jnp.int32))
@@ -758,7 +826,6 @@ def place_lifecycle_batched(fleet: Fleet, demands: jax.Array,
     # O(L·K) + the output row instead of O(L·N).
 
     wc_index = jnp.arange(len(WALK_COUNTS), dtype=jnp.int32)[None, :]
-    arrivals = (jnp.arange(E)[None, :] < n_ev[:, None]) & (demands > 0)
 
     def inner_cond(c):
         return jnp.any((c[3] < n_ev) & ~c[4])

@@ -49,7 +49,11 @@ sequential kernels run on that lane.
 
 Padding: arrays are padded up to the 1024-node tile; a scalar ``n_valid``
 masks padded lanes out of both the lo/hi reduction and the score output
-(padded scores are +inf, so they can never enter a shortlist).
+(padded scores are +inf, so they can never enter a shortlist).  Sweep 2
+optionally widens that mask by a room threshold: a node whose free-chip
+``room`` is below the scalar ``room_min`` scores +inf as well (the
+placement engine's eligibility rule; with the marginal streams the ``cap``
+stream it already reads is the room, so no stream is added).
 
 **Output layout.**  Mosaic accepts only (8, 128)-aligned blocks (or whole
 arrays), so every per-tile result — the (lo, hi) pairs of sweep 1, the
@@ -211,14 +215,26 @@ def _lohi_kernel(n_ref, ec_ref, pue_ref, ci_ref, fc_ref, eff_ref, sw_ref,
     out_ref[...] = _lohi_tile(terms, valid, fids)
 
 
+def _room_valid(valid, rest, n_room, rd):
+    """Widen the padded-tail mask by the room threshold.  ``n_room`` refs
+    sit just before the lo/hi block: none (no threshold), the (1, 1)
+    ``room_min`` scalar alone (the marginal ``cap`` stream, ``rest[1]``,
+    is the room), or the room stream and then ``room_min``."""
+    if not n_room:
+        return valid
+    room_ref = rest[-7] if n_room == 2 else rest[1]
+    return valid & (rd(room_ref) >= rd(rest[-6])[0, 0])
+
+
 def _topk_kernel(n_ref, ec_ref, pue_ref, ci_ref, fc_ref, eff_ref, sw_ref,
-                 *rest, k: int):
+                 *rest, k: int, n_room: int):
     lohi_ref, w_ref, score_ref, tmin_ref, targ_ref = rest[-5:]
     ti = pl.program_id(0)
     fids = _flat_ids()
-    valid = fids + ti * TILE < n_ref[0, 0]
+    valid = _room_valid(fids + ti * TILE < n_ref[0, 0], rest, n_room,
+                        lambda r: r[...])
     terms, w5 = _read_terms(ec_ref, pue_ref, ci_ref, fc_ref, eff_ref, sw_ref,
-                            rest, 5)
+                            rest, 5 + n_room)
     score = _tile_score(terms, lohi_ref[...], w_ref[...], w5)
     score = jnp.where(valid, score, jnp.inf)
     score_ref[...] = score
@@ -238,13 +254,14 @@ def _lohi_kernel_b(n_ref, ec_ref, pue_ref, ci_ref, fc_ref, eff_ref, sw_ref,
 
 
 def _topk_kernel_b(n_ref, ec_ref, pue_ref, ci_ref, fc_ref, eff_ref, sw_ref,
-                   *rest, k: int):
+                   *rest, k: int, n_room: int):
     lohi_ref, w_ref, score_ref, tmin_ref, targ_ref = rest[-5:]
     ti = pl.program_id(1)
     fids = _flat_ids()
-    valid = fids + ti * TILE < n_ref[0, 0]
+    valid = _room_valid(fids + ti * TILE < n_ref[0, 0], rest, n_room,
+                        lambda r: r[0])
     terms, w5 = _read_terms(ec_ref, pue_ref, ci_ref, fc_ref, eff_ref, sw_ref,
-                            rest, 5, lane=0)
+                            rest, 5 + n_room, lane=0)
     score = _tile_score(terms, lohi_ref[0], w_ref[...], w5)
     score = jnp.where(valid, score, jnp.inf)
     score_ref[0] = score
@@ -288,6 +305,30 @@ def _marginal_ops(marginal, en, per_lane=False):
             [en.reshape(1, 4).astype(jnp.float32)])
 
 
+def _room_ops(room, room_min, marginal, shape, per_lane=False):
+    """(extra in_specs, extra operands, n_room) for the room threshold:
+    the room stream (unless the marginal ``cap`` stream serves as the
+    room) and the ``room_min`` scalar, one per lane when batched."""
+    if room_min is None:
+        return [], [], 0
+    if per_lane:
+        L = shape[0]
+        specs = [pl.BlockSpec((1, 1, 1), lambda l, t: (l, 0, 0))]
+        ops = [room_min.reshape(L, 1, 1).astype(jnp.float32)]
+        node_spec = _NODE_SPEC_B
+    else:
+        specs = [pl.BlockSpec((1, 1), lambda t: (0, 0))]
+        ops = [room_min.reshape(1, 1).astype(jnp.float32)]
+        node_spec = _NODE_SPEC
+    if marginal:
+        if room is not None:
+            raise ValueError("with the marginal streams the kernel reads "
+                             "`cap` as the room: pass no `room`")
+        return specs, ops, 1
+    return ([node_spec] + specs,
+            [room.astype(jnp.float32).reshape(shape)] + ops, 2)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def maiz_lohi_pallas(ec, pue, ci_now, ci_fc, eff, sched, n_valid, *,
                      pk=None, cap=None, ct=None, en=None,
@@ -319,9 +360,12 @@ def maiz_lohi_pallas(ec, pue, ci_now, ci_fc, eff, sched, n_valid, *,
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def maiz_topk_pallas(ec, pue, ci_now, ci_fc, eff, sched, n_valid, lohi,
                      weights, *, k: int, pk=None, cap=None, ct=None, en=None,
-                     interpret: bool = False):
+                     room=None, room_min=None, interpret: bool = False):
     """Sweep 2: scores + per-tile top-k.  Returns (scores (N,) with +inf in
-    the padded tail, tile_topk_scores (nt, k), tile_topk_idx (nt, k))."""
+    the padded tail, tile_topk_scores (nt, k), tile_topk_idx (nt, k)).
+    With ``room_min`` (a scalar), nodes whose ``room`` (N,) is below it
+    score +inf too (see ``ops.maiz_ranking_topk``); with the marginal
+    streams ``cap`` is the room and ``room`` is omitted."""
     n = ec.shape[0]
     assert n % TILE == 0, n
     _check_tile_k(k)
@@ -334,10 +378,13 @@ def maiz_topk_pallas(ec, pue, ci_now, ci_fc, eff, sched, n_valid, lohi,
         arrs += [pk, cap, ct]
     args, shape2d = _node_args(arrs, nt)
     en_specs, en_ops = _marginal_ops(marginal, en)
+    room_specs, room_ops, n_room = _room_ops(room, room_min, marginal,
+                                             shape2d)
     scores, tmin, targ = pl.pallas_call(
-        functools.partial(_topk_kernel, k=k),
+        functools.partial(_topk_kernel, k=k, n_room=n_room),
         grid=(nt,),
-        in_specs=[_SCALAR_SPEC] + [_NODE_SPEC] * len(args) + en_specs + [
+        in_specs=[_SCALAR_SPEC] + [_NODE_SPEC] * len(args) + en_specs
+        + room_specs + [
             pl.BlockSpec((r, 2), lambda t: (0, 0)),      # lo/hi
             pl.BlockSpec((1, 4), lambda t: (0, 0)),      # weights
         ],
@@ -348,7 +395,7 @@ def maiz_topk_pallas(ec, pue, ci_now, ci_fc, eff, sched, n_valid, lohi,
             jax.ShapeDtypeStruct(shape2d, jnp.int32),
         ],
         interpret=interpret,
-    )(n_valid, *args, *en_ops, lohi, weights.reshape(1, 4))
+    )(n_valid, *args, *en_ops, *room_ops, lohi, weights.reshape(1, 4))
     return (scores.reshape(n), _tile_cands(tmin, nt, k),
             _tile_cands(targ, nt, k))
 
@@ -385,11 +432,13 @@ def maiz_lohi_pallas_b(ec, pue, ci_now, ci_fc, eff, sched, n_valid, *,
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def maiz_topk_pallas_b(ec, pue, ci_now, ci_fc, eff, sched, n_valid, lohi,
                        weights, *, k: int, pk=None, cap=None, ct=None,
-                       en=None, interpret: bool = False):
+                       en=None, room=None, room_min=None,
+                       interpret: bool = False):
     """Batched sweep 2: node arrays (L, N), ``lohi`` (L, R, 2), shared
-    ``weights`` (4,), ``en`` (L, 4).  Returns (scores (L, N'), tmin
-    (L, nt, k), targ (L, nt, k)) from ONE (L, nt)-grid launch; each lane is
-    identical to the sequential kernel run on that lane."""
+    ``weights`` (4,), ``en`` (L, 4), ``room_min`` (L,).  Returns (scores
+    (L, N'), tmin (L, nt, k), targ (L, nt, k)) from ONE (L, nt)-grid
+    launch; each lane is identical to the sequential kernel run on that
+    lane."""
     L, n = ec.shape
     assert n % TILE == 0, n
     _check_tile_k(k)
@@ -402,10 +451,13 @@ def maiz_topk_pallas_b(ec, pue, ci_now, ci_fc, eff, sched, n_valid, lohi,
         arrs += [pk, cap, ct]
     args = [a.reshape(L, nt * SUBLANES, LANES) for a in arrs]
     en_specs, en_ops = _marginal_ops(marginal, en, per_lane=True)
+    room_specs, room_ops, n_room = _room_ops(
+        room, room_min, marginal, (L, nt * SUBLANES, LANES), per_lane=True)
     scores, tmin, targ = pl.pallas_call(
-        functools.partial(_topk_kernel_b, k=k),
+        functools.partial(_topk_kernel_b, k=k, n_room=n_room),
         grid=(L, nt),
-        in_specs=[_SCALAR_SPEC_B] + [_NODE_SPEC_B] * len(args) + en_specs + [
+        in_specs=[_SCALAR_SPEC_B] + [_NODE_SPEC_B] * len(args) + en_specs
+        + room_specs + [
             pl.BlockSpec((1, r, 2), lambda l, t: (l, 0, 0)),   # lo/hi
             pl.BlockSpec((1, 4), lambda l, t: (0, 0)),         # weights
         ],
@@ -416,6 +468,6 @@ def maiz_topk_pallas_b(ec, pue, ci_now, ci_fc, eff, sched, n_valid, lohi,
             jax.ShapeDtypeStruct((L, nt * SUBLANES, LANES), jnp.int32),
         ],
         interpret=interpret,
-    )(n_valid, *args, *en_ops, lohi, weights.reshape(1, 4))
+    )(n_valid, *args, *en_ops, *room_ops, lohi, weights.reshape(1, 4))
     return (scores.reshape(L, n), _tile_cands(tmin, nt, k),
             _tile_cands(targ, nt, k))

@@ -40,6 +40,8 @@ def maiz_ranking_topk(ec, pue, ci_now, ci_fc, eff, sched, weights, *,
                       cap: Optional[jax.Array] = None,
                       chips_total: Optional[jax.Array] = None,
                       en: Optional[jax.Array] = None,
+                      room: Optional[jax.Array] = None,
+                      room_min: Optional[jax.Array] = None,
                       interpret: Optional[bool] = None
                       ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Fleet-scale fused MAIZ ranking with a merged top-k shortlist.
@@ -56,6 +58,13 @@ def maiz_ranking_topk(ec, pue, ci_now, ci_fc, eff, sched, weights, *,
     (R = 5); omitted, the historical 4-term score is computed bit-exactly.
     With a traced ``en[3] == 0`` the fifth term adds ±0.0 — a bitwise
     no-op (see ``kernels.maizx_rank``).
+
+    ``room_min`` (a scalar) masks every node whose ``room`` (N,) is below
+    it, in the same pass: a masked node scores +inf in the returned
+    ``scores`` too, not only in the top-k.  With the marginal streams the
+    kernel compares ``cap`` and ``room`` is omitted.  Where fewer than k'
+    nodes are unmasked, the tail of the top-k scores +inf and its node
+    ids name no node: they may repeat or lie past N.
 
     Returns (scores (N,), topk_scores (k',), topk_nodes (k',)) with
     k' = min(k, N), ordered lexicographically by (score, node index) —
@@ -88,7 +97,8 @@ def maiz_ranking_topk(ec, pue, ci_now, ci_fc, eff, sched, weights, *,
         lohi = maiz_lohi_pallas(*args, n_valid, interpret=interpret, **mkw)
     scores, tmin, targ = maiz_topk_pallas(
         *args, n_valid, lohi, weights.astype(jnp.float32), k=k_tile,
-        interpret=interpret, **mkw)
+        interpret=interpret, room_min=room_min,
+        room=None if room is None else padded(room), **mkw)
     scores = scores[:n]
     if k_out > k_tile:
         # the tile-local k is capped (unrolled extraction, MAX_TILE_K): a
@@ -109,15 +119,19 @@ def maiz_ranking_topk_batched(ec, pue, ci_now, ci_fc, eff, sched, weights, *,
                               cap: Optional[jax.Array] = None,
                               chips_total: Optional[jax.Array] = None,
                               en: Optional[jax.Array] = None,
+                              room: Optional[jax.Array] = None,
+                              room_min: Optional[jax.Array] = None,
                               interpret: Optional[bool] = None,
                               mesh: Optional[jax.sharding.Mesh] = None
                               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Batched ``maiz_ranking_topk`` over a leading ensemble-lane axis.
 
     Node arrays (L, N), shared ``weights`` (4,), optional per-lane ``lohi``
-    (L, R, 2) and marginal streams (``pk``/``cap``/``chips_total`` (L, N),
-    ``en`` (L, 4)).  ONE (L × node-tiles)-grid kernel launch scores every
-    lane; per-lane tile candidates are merged by one batched ``lax.top_k``.
+    (L, R, 2), marginal streams (``pk``/``cap``/``chips_total`` (L, N),
+    ``en`` (L, 4)) and room threshold (``room`` (L, N), ``room_min``
+    (L,); masked nodes score +inf, as in ``maiz_ranking_topk``).  ONE
+    (L × node-tiles)-grid kernel launch scores every lane; per-lane tile
+    candidates are merged by one batched ``lax.top_k``.
     Each lane's (scores, topk_scores, topk_nodes) is identical to the
     sequential ``maiz_ranking_topk`` on that lane — the round-boundary
     sweep of ``placement.place_lifecycle_batched`` relies on this for
@@ -133,7 +147,8 @@ def maiz_ranking_topk_batched(ec, pue, ci_now, ci_fc, eff, sched, weights, *,
     if mesh is not None:
         return _topk_batched_sharded(
             mesh, (ec, pue, ci_now, ci_fc, eff, sched), weights, k, lohi,
-            dict(pk=pk, cap=cap, chips_total=chips_total, en=en), interpret)
+            dict(pk=pk, cap=cap, chips_total=chips_total, en=en, room=room,
+                 room_min=room_min), interpret)
     if interpret is None:
         interpret = _default_interpret()
     L, n = ec.shape
@@ -154,7 +169,8 @@ def maiz_ranking_topk_batched(ec, pue, ci_now, ci_fc, eff, sched, weights, *,
         lohi = maiz_lohi_pallas_b(*args, n_valid, interpret=interpret, **mkw)
     scores, tmin, targ = maiz_topk_pallas_b(
         *args, n_valid, lohi, weights.astype(jnp.float32), k=k_tile,
-        interpret=interpret, **mkw)
+        interpret=interpret, room_min=room_min,
+        room=None if room is None else padded(room), **mkw)
     scores = scores[:, :n]
     if k_out > k_tile:
         # same oversized-shortlist fallback as the sequential wrapper,
@@ -175,8 +191,9 @@ def _topk_batched_sharded(mesh, streams, weights, k, lohi, marginal,
     node_spec = P("e", "n") if split_n else P("e")
     names = [k_ for k_, v in marginal.items() if v is not None]
     ops_ = list(streams) + [marginal[k_] for k_ in names] + [lohi, weights]
+    per_lane = ("en", "room_min")
     specs = ([node_spec] * len(streams)
-             + [P("e") if k_ == "en" else node_spec for k_ in names]
+             + [P("e") if k_ in per_lane else node_spec for k_ in names]
              + [P("e"), P()])
     n_local = streams[0].shape[1] // (mesh.shape["n"] if split_n else 1)
 

@@ -151,11 +151,24 @@ def test_maiz_ranking_kernel_marginal_weight_zero_is_bitwise_noop(rng):
     np.testing.assert_array_equal(np.asarray(i4), np.asarray(i5))
 
 
-@pytest.mark.parametrize("marginal", [False, True])
-def test_maiz_ranking_topk_batched_matches_sequential(marginal, rng):
+def _room_kw(marginal, masked, cap, room_min):
+    """Room-threshold arguments: with the marginal streams the kernel
+    reads ``cap`` as the room, else the room is a stream of its own."""
+    if not masked:
+        return {}
+    return (dict(room_min=room_min) if marginal
+            else dict(room=cap, room_min=room_min))
+
+
+@pytest.mark.parametrize("marginal,masked",
+                         [(False, False), (True, False),
+                          (False, True), (True, True)],
+                         ids=["False", "True", "False-room", "True-room"])
+def test_maiz_ranking_topk_batched_matches_sequential(marginal, masked, rng):
     """Every lane of the ONE-launch (L x node-tiles) batched kernel is
     bitwise the sequential kernel on that lane — the property the
-    ensemble driver's scan parity rests on."""
+    ensemble driver's scan parity rests on; with a room threshold of
+    each lane's own too."""
     L, n = 3, 2000
     lanes = [_rank_streams(rng, n) for _ in range(L)]
     stack = [jnp.stack([lane[i] for lane in lanes]) for i in range(9)]
@@ -163,12 +176,15 @@ def test_maiz_ranking_topk_batched_matches_sequential(marginal, rng):
     en = jnp.asarray([[0.35, 0.65, 50.0, 0.2],
                       [0.20, 0.80, 0.0, 0.4],
                       [0.30, 0.70, 120.0, 0.0]], jnp.float32)
+    room_min = jnp.asarray([1, 40, 100], jnp.int32)
     mkw_b = dict(pk=pk, cap=cap, chips_total=ct, en=en) if marginal else {}
     sb, tb, ib = maiz_ranking_topk_batched(
-        ec, pue, ci, fc, eff, sw, W4, k=16, interpret=True, **mkw_b)
+        ec, pue, ci, fc, eff, sw, W4, k=16, interpret=True,
+        **mkw_b, **_room_kw(marginal, masked, cap, room_min))
     for l in range(L):
         mkw = dict(pk=pk[l], cap=cap[l], chips_total=ct[l],
                    en=en[l]) if marginal else {}
+        mkw.update(_room_kw(marginal, masked, cap[l], room_min[l]))
         s, t, i = maiz_ranking_topk(
             ec[l], pue[l], ci[l], fc[l], eff[l], sw[l], W4, k=16,
             interpret=True, **mkw)
@@ -177,6 +193,68 @@ def test_maiz_ranking_topk_batched_matches_sequential(marginal, rng):
         np.testing.assert_array_equal(np.asarray(tb[l]).view(np.int32),
                                       np.asarray(t).view(np.int32))
         np.testing.assert_array_equal(np.asarray(ib[l]), np.asarray(i))
+
+
+def _room_case(layout, n, rng):
+    """Free chips (0-64) for a room-threshold case at ``room_min`` 32:
+    about half the nodes qualify (``ragged``: n off the tile, so the
+    padded tail is masked too), none of the first tile does
+    (``whole_tile``), or only five do (``few``, fewer than k)."""
+    room = rng.integers(0, 65, n).astype(np.float32)
+    if layout == "whole_tile":
+        room[:1024] = rng.integers(0, 32, 1024)
+    elif layout == "few":
+        room = rng.integers(0, 32, n).astype(np.float32)
+        room[rng.choice(n, 5, replace=False)] = 48.0
+    return jnp.asarray(room)
+
+
+@pytest.mark.parametrize("marginal", [False, True])
+@pytest.mark.parametrize("layout,n", [("ragged", 2050),
+                                      ("whole_tile", 3000),
+                                      ("few", 2500)])
+def test_maiz_ranking_topk_room_mask_matches_lax_topk(layout, n, marginal,
+                                                      rng):
+    """Nodes below the room threshold score +inf in the scores and are
+    ranked last: the top-k equals lax.top_k over the unmasked kernel's
+    scores masked in jnp, ties included; past the nodes with room the
+    top-k scores +inf.  The batched kernel agrees, a threshold per lane."""
+    ec, pue, ci, fc, eff, sw, pk, _, ct = _rank_streams(rng, n)
+    ct = jnp.full((n,), 64.0, jnp.float32)
+    room = _room_case(layout, n, rng)
+    en = jnp.asarray([0.35, 0.65, 50.0, 0.2], jnp.float32)
+    mkw = dict(pk=pk, cap=room, chips_total=ct, en=en) if marginal else {}
+    args = (ec, pue, ci, fc, eff, sw, W4)
+    k = 16
+    full, _, _ = maiz_ranking_topk(*args, k=k, interpret=True, **mkw)
+    want = jnp.where(room >= 32, full, jnp.inf)
+    neg, idx = jax.lax.top_k(-want, k)
+    n_room = int(jnp.sum(room >= 32))
+    head = min(n_room, k)
+
+    def check(s, t, i):
+        np.testing.assert_array_equal(np.asarray(s).view(np.int32),
+                                      np.asarray(want).view(np.int32))
+        np.testing.assert_array_equal(np.asarray(t)[:head],
+                                      np.asarray(-neg)[:head])
+        np.testing.assert_array_equal(np.asarray(i)[:head],
+                                      np.asarray(idx)[:head])
+        assert np.all(np.isinf(np.asarray(t)[head:]))
+
+    check(*maiz_ranking_topk(*args, k=k, interpret=True, **mkw,
+                             **_room_kw(marginal, True, room, 32)))
+    assert (n_room < k) == (layout == "few")
+    # lane 1 keeps every node: a threshold of 0 masks only the tail
+    bkw = {key: jnp.stack([v, v]) for key, v in mkw.items()}
+    sb, tb, ib = maiz_ranking_topk_batched(
+        *(jnp.stack([a, a]) for a in args[:6]), W4, k=k, interpret=True,
+        **bkw, **_room_kw(marginal, True, jnp.stack([room, room]),
+                          jnp.asarray([32, 0], jnp.int32)))
+    check(sb[0], tb[0], ib[0])
+    np.testing.assert_array_equal(np.asarray(sb[1]).view(np.int32),
+                                  np.asarray(full).view(np.int32))
+    neg1, idx1 = jax.lax.top_k(-full, k)
+    np.testing.assert_array_equal(np.asarray(ib[1]), np.asarray(idx1))
 
 
 def test_maiz_topk_tile_k_limit_is_actionable():

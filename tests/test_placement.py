@@ -223,6 +223,59 @@ def test_lifecycle_migration_pattern():
     _assert_lifecycle_parity(fleet, demands, nodes, shortlist=16)
 
 
+def _full_greenest_fleet(n=1100, n_full=1030, sick=7):
+    """The decision service's state at a small size: the best-scoring
+    nodes (low intensity) are full, every later one has 4 of 8 chips
+    free, and one best-scoring node has room but is out of service.  The
+    first kernel tile holds no node with room and N is off the tile."""
+    i = np.arange(n)
+    ci = np.where(i < n_full, 50.0 + 0.01 * i, 300.0 + 2.0 * (i - n_full))
+    cap = np.where(i < n_full, 0, 4)
+    cap[sick] = 8
+    ones = jnp.ones((n,), jnp.float32)
+    return Fleet(
+        ci_now=jnp.asarray(ci, jnp.float32),
+        ci_forecast=jnp.asarray(ci * 1.05, jnp.float32),
+        pue=1.2 * ones, power_kw=10.0 * ones,
+        capacity=jnp.asarray(cap, jnp.int32),
+        healthy=jnp.asarray(i != sick),
+        straggler_score=jnp.zeros((n,), jnp.float32),
+        flops_per_j=1e9 * ones,
+        chips_total=jnp.full((n,), 8, jnp.int32))
+
+
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_room_aware_shortlist_when_best_nodes_are_full(use_kernel):
+    """The K best-scoring nodes have no room: the shortlist ranks only
+    nodes with room for the call's smallest arrival, so arrivals are
+    placed from it instead of sweeping once each, bit-identically to the
+    oracle.  The stream releases chips on a full node outside the
+    shortlist (it must win the next arrival), pads with no-ops, and
+    fills the fleet until fewer than K+1 nodes have room and arrivals
+    go unplaced; the sick node with room is never chosen."""
+    fleet = _full_greenest_fleet()
+    rng = np.random.default_rng(14)
+    demands = [int(d) for d in rng.integers(1, 4, 30)] + [0, -8, 2]
+    demands += [int(d) if d < 4 else 0 for d in rng.integers(1, 5, 200)]
+    nodes = [-1] * 31 + [3] + [-1] * 201
+    d = jnp.asarray(demands, jnp.int32)
+    v = jnp.asarray(nodes, jnp.int32)
+    a = placement.place_lifecycle_shortlist(
+        fleet, d, v, shortlist=8, use_kernel=use_kernel, interpret=True)
+    b = placement.place_lifecycle_full_rerank(fleet, d, v)
+    np.testing.assert_array_equal(np.asarray(a.node), np.asarray(b.node))
+    np.testing.assert_array_equal(np.asarray(a.capacity),
+                                  np.asarray(b.capacity))
+    out = np.asarray(a.node)
+    assert out[32] == 3                 # the released full node wins
+    assert 7 not in out[np.asarray(demands) > 0]
+    assert (out[np.asarray(demands) > 0] == -1).any()   # the fleet filled
+    wc = np.asarray(a.walk_counts)
+    arrivals = sum(x > 0 for x in demands)
+    assert wc[0] > 0
+    assert int(a.n_sweeps) * 5 <= arrivals, (int(a.n_sweeps), wc)
+
+
 def test_unhealthy_nodes_hard_masked():
     """Health is a hard feasibility constraint in both engines."""
     fleet = synthetic_fleet(128, seed=4)

@@ -56,8 +56,9 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _operands(sharding, lanes, marginal):
-    """(node streams, n_valid, lohi, weights, marginal kwargs) as shapes."""
+def _operands(sharding, lanes, marginal, room=False):
+    """(node streams, n_valid, lohi, weights, marginal and room kwargs) as
+    shapes; with the marginal streams ``cap`` is the room."""
     node = (N,) if lanes is None else (lanes, N)
     lead = () if lanes is None else (lanes,)
     r = 5 if marginal else 4
@@ -67,6 +68,10 @@ def _operands(sharding, lanes, marginal):
     if marginal:
         mkw = dict(pk=f32(node), cap=f32(node), ct=f32(node),
                    en=f32(lead + (4,)))
+    if room:
+        mkw.update(room_min=f32(lead))
+        if not marginal:
+            mkw.update(room=f32(node))
     return (streams, _sds((1, 1), jnp.int32, sharding), f32(lead + (r, 2)),
             f32((4,)), mkw)
 
@@ -83,9 +88,16 @@ def test_lohi_compiles(one_chip, marginal):
                                           interpret=False, **mkw))
 
 
-@pytest.mark.parametrize("marginal", [False, True], ids=["4term", "5term"])
-def test_topk_compiles(one_chip, marginal):
-    streams, n_valid, lohi, w, mkw = _operands(one_chip, None, marginal)
+# the room threshold widens sweep 2's mask only
+TOPK_CASES = dict(argvalues=[(False, False), (True, False),
+                             (False, True), (True, True)],
+                  ids=["4term", "5term", "4term-room", "5term-room"])
+
+
+@pytest.mark.parametrize("marginal,room", **TOPK_CASES)
+def test_topk_compiles(one_chip, marginal, room):
+    streams, n_valid, lohi, w, mkw = _operands(one_chip, None, marginal,
+                                               room)
     _assert_kernel(maiz_topk_pallas.lower(*streams, n_valid, lohi, w, k=K,
                                           interpret=False, **mkw))
 
@@ -97,8 +109,8 @@ def test_lohi_batched_compiles(one_chip, marginal):
                                             interpret=False, **mkw))
 
 
-@pytest.mark.parametrize("marginal", [False, True], ids=["4term", "5term"])
-def test_topk_batched_compiles(one_chip, marginal):
-    streams, n_valid, lohi, w, mkw = _operands(one_chip, L, marginal)
+@pytest.mark.parametrize("marginal,room", **TOPK_CASES)
+def test_topk_batched_compiles(one_chip, marginal, room):
+    streams, n_valid, lohi, w, mkw = _operands(one_chip, L, marginal, room)
     _assert_kernel(maiz_topk_pallas_b.lower(*streams, n_valid, lohi, w, k=K,
                                             interpret=False, **mkw))
