@@ -6,7 +6,9 @@ and gives its parameters; a configuration file gives the deployment.
   ``scheduler.place_events`` on the fleet its own earlier decisions left.
 - ``sim``: what-if studies.  Each call runs a batch of simulated fleet
   trajectories (``simulate_fleet_ensemble`` or ``simulate_fleet_scan``);
-  calls rotate through input sets generated in set-up.
+  calls rotate through input sets generated in set-up.  An ensemble's
+  traffic may name a device layout, ``"shard": "e" | "en"``, which the
+  program lays the batch out by over every visible device.
 
 A driver builds its inputs from the seed in ``setup`` (which also warms
 every shape), runs one unit of work per ``call`` inside the harness's
@@ -168,7 +170,12 @@ class Decide:
                     sweeps=sum(r["sweeps"] for r in self.records))
 
     def sweep_shape(self):
-        return dict(n_nodes=int(self.cap.size), lanes=1, marginal=False)
+        return dict(n_nodes=int(self.cap.size), lanes=1, marginal=False,
+                    room=True)
+
+    def mesh_shape(self):
+        """The decision service runs on one device."""
+        return None
 
     def e2e(self, records, window_t0):
         ms = np.array([(r["t1"] - r["t0"]) * 1e3 for r in records])
@@ -254,6 +261,13 @@ class Sim:
         self.entry = (simulator.simulate_fleet_ensemble
                       if traffic["entry"] == "ensemble"
                       else simulator.simulate_fleet_scan)
+        self.shard = traffic.get("shard", False)
+        if self.shard not in (False, "e", "en"):
+            raise ValueError(f"shard must be 'e' or 'en', got {self.shard!r}")
+        if self.shard and traffic["entry"] != "ensemble":
+            raise ValueError("shard lays out an ensemble; entry "
+                             f"{traffic['entry']!r} runs on one device")
+        self.n_nodes = int(cfg["nodes_per_cell"]) * len(cfg["regions"])
         self.records = []
 
     def setup(self):
@@ -284,7 +298,7 @@ class Sim:
         cfg, tr = self.cfg, self.tr
         T = int(cfg["epochs"])
         hist, hor = int(cfg["history_h"]), int(cfg["horizon_h"])
-        n = int(cfg["nodes_per_cell"]) * len(cfg["regions"])
+        n = self.n_nodes
         region = gen.REGION_ORDER.index(cfg["regions"][0]) \
             if len(cfg["regions"]) == 1 else None
         runs, lanes = [], []
@@ -311,7 +325,7 @@ class Sim:
     def _run(self, inputs):
         runs = inputs[0]
         if self.tr["entry"] == "ensemble":
-            return self.entry(runs)
+            return self.entry(runs, shard=self.shard)
         return [self.entry(*r[:4], jobs=r[4], pad_plan=True) for r in runs]
 
     def call(self):
@@ -334,10 +348,33 @@ class Sim:
                                  lanes=len(self.sets[i][0])))
         return t1 - t0
 
+    def _lanes(self):
+        return len(self.sets[0][0]) if self.tr["entry"] == "ensemble" else 1
+
+    def mesh_shape(self):
+        """``(e, n)``: the lanes' and the nodes' share of the devices the
+        program lays a call out on, read from the sharding of the inputs
+        its plan build gives the first input set, or None where the
+        traffic names no layout."""
+        if not self.shard:
+            return None
+        if not hasattr(self, "_mesh"):
+            buckets = self.sim._ensemble_buckets(self.sets[0][0], True,
+                                                 self.shard)
+            stacked = next(buckets)[3]
+            buckets.close()
+            mesh = getattr(stacked["capacity"].sharding, "mesh", None)
+            shape = {} if mesh is None else mesh.shape
+            self._mesh = int(shape.get("e", 1)), int(shape.get("n", 1))
+            del stacked
+        return self._mesh
+
     def sweep_shape(self):
-        lanes = len(self.sets[0][0]) if self.tr["entry"] == "ensemble" else 1
-        return dict(n_nodes=int(self.cfg["nodes_per_cell"])
-                    * len(self.cfg["regions"]), lanes=lanes, marginal=True)
+        """What one launch of the sweep reads on one device: its block of
+        the lanes and of the nodes."""
+        e, n = self.mesh_shape() or (1, 1)
+        return dict(n_nodes=self.n_nodes // n, lanes=self._lanes() // e,
+                    marginal=True)
 
     def lane_epochs(self, records):
         return sum(r["lanes"] * int(self.cfg["epochs"])
@@ -361,8 +398,12 @@ class Sim:
         deferral is configured), land on a node in service, never overfill
         a node, are left out only when no node had room, and the counters
         agree.  A sample of lanes drawn from the seed: the reference
-        replays each placement (score gap to its best) and recomputes the
-        emissions of every epoch."""
+        replays each placement and recomputes the emissions of every
+        epoch.  It scores each placement's gap to its best, or, where the
+        traffic gives ``check.events``, that many arrivals of the lane
+        drawn from the seed and the last arrival of its busiest epoch; it
+        checks every other placement for room and health."""
+        events = self.tr["check"].get("events")
         audit = reference.Audit()
         pairs = []
         for ci, r in enumerate(self.records):
@@ -377,14 +418,34 @@ class Sim:
         for p in rng.choice(len(pairs), n_s, replace=False).tolist():
             ci, li = pairs[p]
             r = self.records[ci]
-            a, e = reference.simulate_lane(self.sets[r["set"]][1][li],
-                                           follow=r["res"][li])
+            lane = self.sets[r["set"]][1][li]
+            score = None if events is None else _gap_sample(
+                lane, int(events), rng)
+            a, e = reference.simulate_lane(lane, follow=r["res"][li],
+                                           score=score)
             audit.merge(a)
             emis = max(emis, e)
         info = dict(lanes=len(pairs), lanes_replayed=n_s,
                     placements_checked=audit.checked)
+        if events is not None:
+            info["gaps_scored"] = audit.scored
         return {"place_gap": audit.gap, "emis_rel": emis,
                 "invalid": audit.invalid}, info
+
+
+def _gap_sample(lane, n, rng):
+    """The arrivals of one lane whose gap the check scores: ``n`` drawn
+    from ``rng``, and the last arrival of the epoch with the most of
+    them, where the fleet is fullest.  A boolean per job."""
+    arrive = np.asarray(lane["arrive"])
+    due = np.flatnonzero(arrive < lane["epochs"])
+    pick = np.zeros(arrive.size, bool)
+    if not due.size:
+        return pick
+    pick[rng.choice(due, min(n, due.size), replace=False)] = True
+    busiest = np.argmax(np.bincount(arrive[due]))
+    pick[due[arrive[due] == busiest][-1]] = True
+    return pick
 
 
 def _cheap_lane(lane, res):

@@ -44,6 +44,11 @@ SCOPES = ("forecast", "epoch_pre", "epoch_post", "placement_walk",
           "rank_sweep", "router")
 MODULES_LINE = "XLA Modules"
 UNSCOPED, NO_TABLE = "(no scope)", "(no table)"
+# the exchange between chips: its own bucket, whatever scope it sits in
+COLLECTIVE = "(collective)"
+COLLECTIVE_OPS = ("all-reduce", "all-gather", "all-to-all",
+                  "collective-permute", "collective-broadcast",
+                  "reduce-scatter")
 TRACE_ROOT = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), ".bench_cache", "trace")
@@ -54,6 +59,9 @@ _MODULE = re.compile(r"^HloModule ([^\s,]+)", re.M)
 _WRAPPED = re.compile(r"\w+\((.*)\)")
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?(%[\w.\-]+) .*\{\s*$")
 _CALLS = re.compile(r"(?:calls|to_apply|body|condition)=(%[\w.\-]+)")
+_PARTITIONS = re.compile(r"^HloModule [^\n]*\bnum_partitions=(\d+)", re.M)
+# an instruction's opcode: the first word after its shape that opens "("
+_OPCODE = re.compile(r" = .*? ([a-z][\w\-]*)\(")
 
 
 def say(*a):
@@ -144,11 +152,20 @@ def innermost_scope(op_name: str):
 
 def scope_table(text: str):
     """``(module name, {instruction: innermost scope or None})`` of one
-    optimized HLO module's text.  An instruction the compiler made without
-    metadata (a layout fusion, say) takes the scope most of the
-    instructions of the computations it calls carry."""
+    optimized HLO module's text.  A collective (``COLLECTIVE_OPS``, their
+    ``-start`` and ``-done``) maps to ``COLLECTIVE``, whatever its
+    metadata.  Any other instruction the compiler made without metadata
+    (a layout fusion, say) takes the scope most of the instructions of
+    the computations it calls carry, or ``COLLECTIVE`` where those carry
+    none and hold a collective (an async wrapper).  In a program laid out
+    over several devices (``num_partitions`` above 1) the partitioner
+    adds more such instructions (the buffers a collective fills, each
+    shard's slices): one that calls nothing scoped takes the scope most
+    instructions of its own computation carry."""
     m = _MODULE.search(text)
-    table, calls = {}, {}
+    p = _PARTITIONS.search(text)
+    partitioned = p is not None and int(p.group(1)) > 1
+    table, calls, comp_of, exchanges = {}, {}, {}, set()
     comp, in_comp = None, collections.defaultdict(collections.Counter)
     for line in text.splitlines():
         head = _COMPUTATION.match(line)
@@ -158,36 +175,48 @@ def scope_table(text: str):
         i = _INSTR.match(line)
         if not i:
             continue
+        op = _OPCODE.search(line)
         o = _OP_NAME.search(line)
-        if o:
+        if op and op.group(1).removesuffix("-start").removesuffix(
+                "-done") in COLLECTIVE_OPS:
+            table[i.group(1)] = COLLECTIVE
+            exchanges.add(comp)
+        elif o:
             table[i.group(1)] = scope = innermost_scope(o.group(1))
             if scope:
                 in_comp[comp][scope] += 1
         else:
             table[i.group(1)] = None
             calls[i.group(1)] = _CALLS.findall(line)
+            comp_of[i.group(1)] = comp
     for name, called in calls.items():
         votes = sum((in_comp[c] for c in called), collections.Counter())
         if votes:
             table[name] = votes.most_common(1)[0][0]
+        elif any(c in exchanges for c in called):    # an async wrapper
+            table[name] = COLLECTIVE
+        elif partitioned and in_comp[comp_of[name]]:
+            table[name] = in_comp[comp_of[name]].most_common(1)[0][0]
     return (m.group(1) if m else None), table
 
 
 def scope_times(ext: dict, window, tables) -> dict:
-    """Device self time (ns) in ``window`` by innermost named scope.
+    """Device self time (ns) in ``window`` by innermost named scope,
+    averaged over the device planes (as ``trace.reduce``'s busy time).
 
     Each operation belongs to the ``XLA Modules`` event it starts in; of
     the ``tables`` for that module's name, the one that holds the most of
-    the event's operations maps it.  Keys: each scope, ``UNSCOPED`` (an
-    instruction of a table with no scope) and ``NO_TABLE`` (no table for
+    the event's operations maps it.  Keys: each scope, ``COLLECTIVE``
+    (the exchange between chips), ``UNSCOPED`` (an instruction of a table
+    with no scope) and ``NO_TABLE`` (no table for
     the module or the instruction); ``"by_module"`` splits the last by
-    module name."""
+    module name, ``"unscoped_ops"`` the one before by instruction."""
     w0, w1 = window
     by_name = collections.defaultdict(list)
     for name, table in tables:
         by_name[name].append(table)
     out = collections.Counter()
-    no_table = collections.Counter()
+    no_table, unscoped = collections.Counter(), collections.Counter()
     for dev, evs in ext["ops"].items():
         mods = ext["modules"].get(dev, [])
         starts = [m[1] for m in mods]
@@ -213,8 +242,12 @@ def scope_times(ext: dict, window, tables) -> dict:
                 no_table[(mod or "?").split("(")[0]] += ns
             else:
                 out[table[op] or UNSCOPED] += ns
-    res = dict(out)
-    res["by_module"] = dict(no_table)
+                if table[op] is None:
+                    unscoped[op] += ns
+    n_dev = max(len(ext["ops"]), 1)
+    res = {k: v / n_dev for k, v in out.items()}
+    res["by_module"] = {k: v / n_dev for k, v in no_table.items()}
+    res["unscoped_ops"] = {k: v / n_dev for k, v in unscoped.items()}
     return res
 
 
@@ -266,7 +299,8 @@ def _program_texts(ctx) -> list:
     ensemble = drv.tr["entry"] == "ensemble"
     out = []
     for i in sorted({r["set"] for r in recs}):
-        for t in texts(drv.sets[i][0], ensemble=ensemble, pad_plan=True):
+        for t in texts(drv.sets[i][0], ensemble=ensemble, pad_plan=True,
+                       shard=drv.shard):
             if t not in out:
                 out.append(t)
     return out
@@ -384,7 +418,8 @@ def _print(rep, modules):
         say("layers: no scope table (the program gives no HLO text)")
         return
     sc = rep["scopes"]
-    share = {k: v for k, v in sc.items() if k != "by_module"}
+    share = {k: v for k, v in sc.items()
+             if k not in ("by_module", "unscoped_ops")}
     say(f"layers: scope tables of {modules}; device self time by scope "
         "(% of busy): " + ", ".join(
             f"{k} {100 * v / busy:.2f}" for k, v in
@@ -394,6 +429,9 @@ def _print(rep, modules):
             f"{m} {100 * v / busy:.2f}%" for m, v in
             sorted(sc["by_module"].items(), key=lambda kv: -kv[1]))
             if sc["by_module"] else "") if busy > 0 else "")
+    say("layers: largest unscoped ops (ms): " + ", ".join(
+        f"{op} {v * 1e-6:.3f}" for op, v in
+        sorted(sc["unscoped_ops"].items(), key=lambda kv: -kv[1])[:8]))
 
 
 def _boundary(what, fn, ctx):

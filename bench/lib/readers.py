@@ -18,7 +18,12 @@ def idle_pct(ctx):
 
 
 def sweep_roofline_pct(ctx):
-    """Least HBM time of the sweeps that ran over their device time."""
+    """Least HBM time of the sweeps that ran over their device time.
+
+    Launches and their time are averaged over the trace's device planes
+    (as ``busy_ns`` is), and ``ctx.sweep_shape`` is what one launch reads
+    on one device, so on a mesh this is the chips' mean share, each
+    weighted by its kernel time."""
     red = ctx.trace
     if red is None or ctx.peak is None:
         return None

@@ -110,43 +110,79 @@ class Audit:
         self.gap = 0.0
         self.invalid = 0
         self.checked = 0
+        self.scored = 0
 
     def merge(self, other: "Audit"):
         self.gap = max(self.gap, other.gap)
         self.invalid += other.invalid
         self.checked += other.checked
+        self.scored += other.scored
 
 
 def place_events(sc: Scorer, cap, healthy, demands, nodes, follow=None,
-                 audit: Audit = None):
+                 audit: Audit = None, score=None):
     """Greedy placement of one decision's events, in order.  Returns the
     chosen node per event (-1 for a job that fits nowhere) and the final
     capacity.  With ``follow``, the answers are those of ``follow`` and
-    ``audit`` records their gaps."""
+    ``audit`` records their gaps.
+
+    ``score`` (with ``follow``; a boolean per event) names the arrivals
+    whose gap is measured, each by the argmin over all nodes.  Every other
+    arrival costs O(1): its node is checked for room and health, and a job
+    left out is checked against every healthy node, only where one may
+    still have room.  ``invalid`` counts the same either way."""
     cap = np.asarray(cap, np.int64).copy()
     healthy = np.asarray(healthy, bool)
     s = sc.score(cap)
     out = np.full(len(demands), -1, np.int64)
+    demands, nodes = np.asarray(demands).tolist(), np.asarray(nodes).tolist()
+    if follow is not None:
+        follow = np.asarray(follow).tolist()
+    if score is not None:
+        score = np.asarray(score, bool).tolist()
+    stale = []          # nodes whose score is out of date (unscored arrivals)
+    room_hi = INF       # bound on the most room a healthy node has
     for e in range(len(demands)):
-        d = int(demands[e])
+        d = demands[e]
         if d == 0:
             continue
         if d < 0:
-            c = int(nodes[e])
-            if follow is not None and int(follow[e]) != c:
+            c = nodes[e]
+            if follow is not None and follow[e] != c:
                 audit.invalid += 1
             cap[c] -= d
+            room_hi = max(room_hi, int(cap[c]))
             s[c] = sc.score(cap[c:c + 1], slice(c, c + 1))[0]
             out[e] = c
             continue
+        if score is not None and not score[e]:
+            c = follow[e]
+            audit.checked += 1
+            if c < 0:
+                if d <= room_hi:
+                    room_hi = int(np.max(cap, where=healthy, initial=-1))
+                    audit.invalid += int(room_hi >= d)
+            elif not (0 <= c < cap.size and healthy[c] and cap[c] >= d):
+                audit.invalid += 1
+                c = -1
+            if c >= 0:
+                cap[c] -= d
+                stale.append(c)
+            out[e] = c
+            continue
+        if stale:
+            idx = np.asarray(stale)
+            s[idx] = sc.score(cap[idx], idx)
+            stale = []
         feas = healthy & (cap >= d)
         masked = np.where(feas, s, INF)
         b = int(np.argmin(masked))
         if not feas[b]:
             b = -1
-        c = b if follow is None else int(follow[e])
+        c = b if follow is None else follow[e]
         if follow is not None:
             audit.checked += 1
+            audit.scored += 1
             if c < 0:
                 audit.invalid += int(b >= 0)
             elif not (0 <= c < cap.size and feas[c]):
@@ -195,7 +231,8 @@ def forecast_mean(history, horizon):
     return float(np.maximum(fc, 0.0).mean())
 
 
-def simulate_lane(lane: dict, dt=np.float64, follow: dict = None):
+def simulate_lane(lane: dict, dt=np.float64, follow: dict = None,
+                  score=None):
     """One trajectory of the simulator (no deferral, migration or outage).
 
     ``lane`` holds the fleet arrays, ``traces`` (R, hours), ``ridx``, the
@@ -203,7 +240,10 @@ def simulate_lane(lane: dict, dt=np.float64, follow: dict = None):
     ``weights`` and ``energy``.  Returns the trajectory (first node and
     start epoch per job, emissions per epoch, placed/completed counts);
     with ``follow`` (another implementation's trajectory) it replays those
-    answers and returns ``(audit, widest relative emission error)``."""
+    answers and returns ``(audit, widest relative emission error)``;
+    ``score`` (a boolean per job) then names the arrivals whose gap is
+    measured (``place_events``), None all of them.  Every epoch's
+    emissions are recomputed in full either way."""
     T, hist, hor = lane["epochs"], lane["history_h"], lane["horizon_h"]
     traces, ridx = np.asarray(lane["traces"], np.float64), lane["ridx"]
     healthy = np.asarray(lane["healthy"], bool)
@@ -250,7 +290,8 @@ def simulate_lane(lane: dict, dt=np.float64, follow: dict = None):
                                         & (follow["start_epoch"][arr] >= 0)))
         out, cap = place_events(sc, cap, healthy, chips[arr],
                                 np.full(arr.size, -1), follow=fol,
-                                audit=audit)
+                                audit=audit,
+                                score=None if score is None else score[arr])
         ok = out >= 0
         node[arr[ok]], start[arr[ok]] = out[ok], t
         end[arr[ok]] = t + dur[arr[ok]]

@@ -92,7 +92,9 @@ def self_times(evs):
 def reduce(tr: dict, window=None) -> dict:
     """Busy union, per-op self time and labelled idle gaps inside
     ``window`` (default: from the first harness span's start to the last
-    one's end).  Times in ns; busy is averaged over the device planes."""
+    one's end).  Times in ns.  Busy time, per-op self time and op counts
+    are averaged over the device planes (a mesh's chips each run their
+    share of every launch); the idle gaps are each plane's own."""
     spans = tr["spans"]
     if window is None:
         if not spans:
@@ -116,7 +118,10 @@ def reduce(tr: dict, window=None) -> dict:
             t = max(t, e)
     n_dev = max(len(tr["ops"]), 1)
     return {"window_ns": w1 - w0, "busy_ns": sum(busy) / n_dev,
-            "per_op_ns": per_op, "op_count": count,
+            "per_op_ns": collections.Counter(
+                {k: v / n_dev for k, v in per_op.items()}),
+            "op_count": collections.Counter(
+                {k: v / n_dev for k, v in count.items()}),
             "gaps": sorted(gaps, key=lambda g: -g[1]), "window": window}
 
 

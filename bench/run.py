@@ -12,7 +12,9 @@ window of ``--seconds`` (``--trace 1`` also records a profiler trace of its
 first calls), then the check of what the window produced against the
 plain reference.  The last line of standard output is one JSON object;
 the last lines of standard error give each number compared beside its
-limit.  Exits non-zero, with no result, where JAX finds no TPU.
+limit.  Exits non-zero, with no result, where JAX finds no TPU, or
+fewer chips than the cell asks for; where the traffic lays the study out
+over the devices (``shard``), also where JAX finds more.
 """
 from __future__ import annotations
 
@@ -185,6 +187,9 @@ def run_cell(cell: dict, seed: int, seconds: float, traced: bool,
     # ---- metrics -------------------------------------------------------
     metrics, device = {}, {"platform": dev.platform, "kind": dev.device_kind,
                            "count": len(devs), "memory_peak_bytes": mem}
+    mesh = drv.mesh_shape()
+    if mesh is not None:
+        device["mesh"] = list(mesh)
     out = {}
     if not traced:
         vals = drv.e2e(records, w0)
@@ -230,6 +235,19 @@ def run_cell(cell: dict, seed: int, seconds: float, traced: bool,
     return result, compared
 
 
+def refusal(cell: dict, devs) -> str:
+    """Why the cell cannot run on the devices JAX found, or ''."""
+    want = int(cell["workload"]["chips"])
+    if devs[0].platform != "tpu" or len(devs) < want:
+        return (f"needs {want} TPU chip(s); JAX found {len(devs)} "
+                f"{devs[0].platform} device(s)")
+    if cell["traffic"].get("shard") and len(devs) != want:
+        return (f"traffic {cell['workload']['traffic']!r} lays its study "
+                f"out over every visible device: needs exactly {want} TPU "
+                f"chip(s); JAX found {len(devs)}")
+    return ""
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -256,15 +274,14 @@ def main(argv=None) -> int:
                       os.path.join(CACHE, "jax"))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    want = int(cell["workload"]["chips"])
     try:
         devs = jax.devices()
     except RuntimeError as e:
         say(f"bench: JAX found no accelerator: {e}")
         return 3
-    if devs[0].platform != "tpu" or len(devs) < want:
-        say(f"bench: needs {want} TPU chip(s); JAX found {len(devs)} "
-            f"{devs[0].platform} device(s)")
+    why = refusal(cell, devs)
+    if why:
+        say(f"bench: {why}")
         return 3
     result, compared = run_cell(cell, args.seed, args.seconds,
                                 bool(args.trace))

@@ -29,7 +29,9 @@ NEW = ("shortlist_hit_pct.decide", "sweep_no_room_pct.decide",
 
 def _old_values(raw):
     ctx = run.Ctx(trace=trace.reduce(raw), trace_raw=raw,
-                  sweep_shape=dict(n_nodes=2048, lanes=1, marginal=False),
+                  # the trace predates the room stream: its own shape
+                  sweep_shape=dict(n_nodes=2048, lanes=1, marginal=False,
+                                   room=False),
                   peak=run.load_peak(run.ROOT, "TPU v5 lite"),
                   counters=dict(calls=2, sweeps=7, lane_epochs=48))
     return {n: run.load_reader(run.ROOT, n)(ctx) for n in OLD}
