@@ -709,7 +709,7 @@ def place_lifecycle_batched(fleet: Fleet, demands: jax.Array,
     overhead across lanes — the enabling structure for
     ``simulator.simulate_fleet_ensemble``.  The shortlist top-k merge is
     the batched ``lax.top_k``; with ``use_kernel`` the round-boundary
-    sweep is instead ONE Pallas launch on a (stalled-lanes × node-tiles)
+    sweep is instead ONE Pallas launch on a (stalled-lanes × node-blocks)
     grid (``repro.kernels.ops.maiz_ranking_topk_batched``), per-lane
     identical to the sequential engine's kernel sweep.  ``mesh`` is the
     ensemble's device mesh when its buffers are sharded: the kernel sweep

@@ -2110,11 +2110,11 @@ def simulate_fleet_ensemble(runs, *, pad_plan: bool = True,
     ``shard="en"`` uses the 2D ``("e", "n")`` mesh instead
     (``distributed.sharding.ensemble_mesh``): the leftover device factor
     splits the *node* axis of the (E, N) fleet buffers, for fleets that do
-    not fit one device — the tile-local top-k merge is unchanged (XLA
+    not fit one device — the block-local top-k merge is unchanged (XLA
     concatenates per-shard candidates before the ``lax.top_k``).
 
     ``use_kernel=True`` members run the batched Pallas sweep — one
-    (stalled-lanes × node-tiles) kernel launch per placement round
+    (stalled-lanes × node-blocks) kernel launch per placement round
     (``placement.place_lifecycle_batched``), per-lane bit-identical to
     the sequential scan driver (interpret mode on CPU, compiled on
     TPU).  XLA cannot partition a compiled Pallas kernel, so on a sharded

@@ -15,9 +15,9 @@ import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.selective_scan import selective_scan
-from repro.kernels.maizx_rank import (MAX_TILE_K, TILE, maiz_lohi_pallas,
-                                      maiz_lohi_pallas_b, maiz_topk_pallas,
-                                      maiz_topk_pallas_b)
+from repro.kernels.maizx_rank import (MAX_TILE_K, block_nodes,
+                                      maiz_lohi_pallas, maiz_lohi_pallas_b,
+                                      maiz_topk_pallas, maiz_topk_pallas_b)
 
 
 def _default_interpret() -> bool:
@@ -46,9 +46,10 @@ def maiz_ranking_topk(ec, pue, ci_now, ci_fc, eff, sched, weights, *,
                       ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Fleet-scale fused MAIZ ranking with a merged top-k shortlist.
 
-    Arrays (N,) any float dtype; pads N up to the 1024-node tile internally
-    (padded lanes are masked, never shortlisted).  Two memory-bound sweeps:
-    a fused term+lo/hi pre-pass and the score+tile-top-k pass; pass ``lohi``
+    Arrays (N,) any float dtype; pads N up to a whole number of kernel
+    blocks internally (``maizx_rank.block_nodes``; padded lanes are masked,
+    never shortlisted).  Two memory-bound sweeps: a fused term+lo/hi
+    pre-pass and the score+block-top-k pass; pass ``lohi``
     (R, 2) to pin the normalizers and skip sweep 1 (the placement engine
     freezes them per decision epoch).
 
@@ -82,7 +83,7 @@ def maiz_ranking_topk(ec, pue, ci_now, ci_fc, eff, sched, weights, *,
     n = ec.shape[0]
     k_out = min(k, n)
     k_tile = min(k_out, MAX_TILE_K)
-    pad = (-n) % TILE
+    pad = block_nodes(n) - n
 
     def padded(x):
         return jnp.pad(x.astype(jnp.float32), (0, pad))
@@ -101,14 +102,15 @@ def maiz_ranking_topk(ec, pue, ci_now, ci_fc, eff, sched, weights, *,
         room=None if room is None else padded(room), **mkw)
     scores = scores[:n]
     if k_out > k_tile:
-        # the tile-local k is capped (unrolled extraction, MAX_TILE_K): a
-        # single tile could hold more than k_tile of the global top-k_out,
+        # the block-local k is capped (unrolled extraction, MAX_TILE_K): a
+        # single block could hold more than k_tile of the global top-k_out,
         # so merge from the full score vector instead — exact, same
         # lower-index tie rule, one extra O(N log k) host pass.
         neg, pos = jax.lax.top_k(-scores, k_out)
         return scores, -neg, pos.astype(jnp.int32)
-    # merge tile top-k's: candidates are (tile, rank)-ordered, so lax.top_k's
-    # lower-index-first tie rule preserves global (score, node) order.
+    # merge block top-k's: candidates are (block, rank)-ordered, so
+    # lax.top_k's lower-index-first tie rule preserves global (score, node)
+    # order.
     neg, pos = jax.lax.top_k(-tmin.reshape(-1), k_out)
     return scores, -neg, targ.reshape(-1)[pos]
 
@@ -130,7 +132,7 @@ def maiz_ranking_topk_batched(ec, pue, ci_now, ci_fc, eff, sched, weights, *,
     (L, R, 2), marginal streams (``pk``/``cap``/``chips_total`` (L, N),
     ``en`` (L, 4)) and room threshold (``room`` (L, N), ``room_min``
     (L,); masked nodes score +inf, as in ``maiz_ranking_topk``).  ONE
-    (L × node-tiles)-grid kernel launch scores every lane; per-lane tile
+    (L × node-blocks)-grid kernel launch scores every lane; per-lane block
     candidates are merged by one batched ``lax.top_k``.
     Each lane's (scores, topk_scores, topk_nodes) is identical to the
     sequential ``maiz_ranking_topk`` on that lane — the round-boundary
@@ -154,7 +156,7 @@ def maiz_ranking_topk_batched(ec, pue, ci_now, ci_fc, eff, sched, weights, *,
     L, n = ec.shape
     k_out = min(k, n)
     k_tile = min(k_out, MAX_TILE_K)
-    pad = (-n) % TILE
+    pad = block_nodes(n) - n
 
     def padded(x):
         return jnp.pad(x.astype(jnp.float32), ((0, 0), (0, pad)))

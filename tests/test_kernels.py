@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
+from repro.kernels.maizx_rank import block_tiles
 from repro.kernels.ops import (flash_attention_op, maiz_ranking_fused,
                                maiz_ranking_topk, maiz_ranking_topk_batched,
                                selective_scan_op)
@@ -48,7 +49,14 @@ def test_flash_attention_block_shape_invariance(blocks, rng):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
 
 
-@pytest.mark.parametrize("n", [1024, 2048, 4096, 1000, 5000])
+# sweep-2 node counts: one block (ragged), one 38-tile block (ragged), and
+# past MAX_BLOCK_TILES tiles: 130 tiles in three blocks of 44, the last
+# padded by two tiles and the ragged tail
+ONE_BLOCK, WIDE_BLOCK, MULTI_BLOCK = 2000, 37 * 1024 + 5, 129 * 1024 + 5
+
+
+@pytest.mark.parametrize("n", [1024, 2048, 4096, 1000, 5000, WIDE_BLOCK,
+                               MULTI_BLOCK])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_maiz_ranking_kernel_matches_ref(n, dtype, rng):
     ec = jnp.asarray(rng.random(n) * 100, dtype)
@@ -111,7 +119,7 @@ def _rank_streams(rng, n):
 W4 = jnp.asarray([0.35, 0.25, 0.25, 0.15], jnp.float32)
 
 
-@pytest.mark.parametrize("n", [1024, 5000])
+@pytest.mark.parametrize("n", [1024, 5000, MULTI_BLOCK])
 @pytest.mark.parametrize("idle", [0.2, 0.35])
 @pytest.mark.parametrize("emb_h", [0.0, 120.0])
 @pytest.mark.parametrize("w_m", [0.0, 0.3])
@@ -160,16 +168,22 @@ def _room_kw(marginal, masked, cap, room_min):
             else dict(room=cap, room_min=room_min))
 
 
-@pytest.mark.parametrize("marginal,masked",
-                         [(False, False), (True, False),
-                          (False, True), (True, True)],
-                         ids=["False", "True", "False-room", "True-room"])
-def test_maiz_ranking_topk_batched_matches_sequential(marginal, masked, rng):
-    """Every lane of the ONE-launch (L x node-tiles) batched kernel is
+BATCHED_CASES = [(m, r, n) for n in (ONE_BLOCK, WIDE_BLOCK, MULTI_BLOCK)
+                 for m, r in ((False, False), (True, False),
+                              (False, True), (True, True))]
+
+
+@pytest.mark.parametrize(
+    "marginal,masked,n", BATCHED_CASES,
+    ids=[f"{m}{'-room' if r else ''}{'' if n == ONE_BLOCK else f'-n{n}'}"
+         for m, r, n in BATCHED_CASES])
+def test_maiz_ranking_topk_batched_matches_sequential(marginal, masked, n,
+                                                      rng):
+    """Every lane of the ONE-launch (L x node-blocks) batched kernel is
     bitwise the sequential kernel on that lane — the property the
     ensemble driver's scan parity rests on; with a room threshold of
-    each lane's own too."""
-    L, n = 3, 2000
+    each lane's own too; in one block and in several."""
+    L = 3
     lanes = [_rank_streams(rng, n) for _ in range(L)]
     stack = [jnp.stack([lane[i] for lane in lanes]) for i in range(9)]
     ec, pue, ci, fc, eff, sw, pk, cap, ct = stack
@@ -199,10 +213,15 @@ def _room_case(layout, n, rng):
     """Free chips (0-64) for a room-threshold case at ``room_min`` 32:
     about half the nodes qualify (``ragged``: n off the tile, so the
     padded tail is masked too), none of the first tile does
-    (``whole_tile``), or only five do (``few``, fewer than k)."""
+    (``whole_tile``), none of the first of several blocks does
+    (``whole_block``), or only five do (``few``, fewer than k)."""
     room = rng.integers(0, 65, n).astype(np.float32)
     if layout == "whole_tile":
         room[:1024] = rng.integers(0, 32, 1024)
+    elif layout == "whole_block":
+        nb, bt = block_tiles(-(-n // 1024))
+        assert nb > 1
+        room[:bt * 1024] = rng.integers(0, 32, bt * 1024)
     elif layout == "few":
         room = rng.integers(0, 32, n).astype(np.float32)
         room[rng.choice(n, 5, replace=False)] = 48.0
@@ -212,7 +231,10 @@ def _room_case(layout, n, rng):
 @pytest.mark.parametrize("marginal", [False, True])
 @pytest.mark.parametrize("layout,n", [("ragged", 2050),
                                       ("whole_tile", 3000),
-                                      ("few", 2500)])
+                                      ("few", 2500),
+                                      ("ragged", WIDE_BLOCK),
+                                      ("ragged", MULTI_BLOCK),
+                                      ("whole_block", MULTI_BLOCK)])
 def test_maiz_ranking_topk_room_mask_matches_lax_topk(layout, n, marginal,
                                                       rng):
     """Nodes below the room threshold score +inf in the scores and are
@@ -255,6 +277,40 @@ def test_maiz_ranking_topk_room_mask_matches_lax_topk(layout, n, marginal,
                                   np.asarray(full).view(np.int32))
     neg1, idx1 = jax.lax.top_k(-full, k)
     np.testing.assert_array_equal(np.asarray(ib[1]), np.asarray(idx1))
+
+
+@pytest.mark.parametrize("n", [ONE_BLOCK, WIDE_BLOCK, MULTI_BLOCK])
+def test_maiz_ranking_topk_ties_across_tiles_take_the_lower_node(n, rng):
+    """Equal scores in different tiles of one block (and, past one block,
+    in different blocks) come out lower node first: with more tied best
+    nodes than k, the shortlist is the k lowest of them, bitwise
+    lax.top_k over the kernel's scores; the batched kernel agrees."""
+    ec, pue, ci, fc, eff, sw = (np.array(a) for a in _rank_streams(rng, n)[:6])
+    # node 1023 (tile 0, last row and lane) before node 1024 (tile 1,
+    # first row and lane): the tied nodes get every input's best value,
+    # so they score alike and below every other node
+    tied = np.unique(np.concatenate([[1023, 1024],
+                                     rng.choice(n, 30, replace=False)]))
+    for a, v in ((ec, 0.0), (pue, 1.0), (ci, 0.0), (fc, 0.0), (eff, 1.0),
+                 (sw, 0.0)):
+        a[tied] = v
+    args = [jnp.asarray(a) for a in (ec, pue, ci, fc, eff, sw)]
+    k = 16
+    s, t, i = maiz_ranking_topk(*args, W4, k=k, interpret=True)
+    st = np.asarray(s)
+    assert np.all(st[tied] == st[tied[0]])
+    assert st[tied[0]] < np.delete(st, tied).min()
+    neg, idx = jax.lax.top_k(-s, k)
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(idx))
+    np.testing.assert_array_equal(np.asarray(t).view(np.int32),
+                                  np.asarray(-neg).view(np.int32))
+    np.testing.assert_array_equal(np.asarray(i), tied[:k])
+    sb, tb, ib = maiz_ranking_topk_batched(
+        *(jnp.stack([a, a]) for a in args), W4, k=k, interpret=True)
+    for l in range(2):
+        np.testing.assert_array_equal(np.asarray(ib[l]), tied[:k])
+        np.testing.assert_array_equal(np.asarray(tb[l]).view(np.int32),
+                                      np.asarray(t).view(np.int32))
 
 
 def test_maiz_topk_tile_k_limit_is_actionable():

@@ -16,8 +16,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.maizx_rank import (maiz_lohi_pallas, maiz_lohi_pallas_b,
-                                      maiz_topk_pallas, maiz_topk_pallas_b)
+from repro.kernels.maizx_rank import (block_tiles, maiz_lohi_pallas,
+                                      maiz_lohi_pallas_b, maiz_topk_pallas,
+                                      maiz_topk_pallas_b)
 
 N = 1_048_576
 K = 33
@@ -56,10 +57,10 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _operands(sharding, lanes, marginal, room=False):
+def _operands(sharding, lanes, marginal, room=False, n=N):
     """(node streams, n_valid, lohi, weights, marginal and room kwargs) as
     shapes; with the marginal streams ``cap`` is the room."""
-    node = (N,) if lanes is None else (lanes, N)
+    node = (n,) if lanes is None else (lanes, n)
     lead = () if lanes is None else (lanes,)
     r = 5 if marginal else 4
     f32 = lambda s: _sds(s, jnp.float32, sharding)
@@ -114,3 +115,31 @@ def test_topk_batched_compiles(one_chip, marginal, room):
     streams, n_valid, lohi, w, mkw = _operands(one_chip, L, marginal, room)
     _assert_kernel(maiz_topk_pallas_b.lower(*streams, n_valid, lohi, w, k=K,
                                             interpret=False, **mkw))
+
+
+# sweep 2 at the benchmark cells' shapes and one region-scale shard: the
+# decision cell's 4-term room sweep over 36 tiles, the study's 5-term sweep
+# over 12 lanes of 12 tiles (``cap`` is the room), and a 512-tile shard;
+# each block of up to MAX_BLOCK_TILES tiles must fit scoped VMEM
+CELL_SHAPES = dict(argvalues=[(None, 36_864, False), (12, 12_288, True),
+                              (1, 524_288, True)],
+                   ids=["decide", "sweep12", "region-shard"])
+
+
+@pytest.mark.parametrize("lanes,n,marginal", **CELL_SHAPES)
+def test_topk_compiles_at_cell_shapes(one_chip, lanes, n, marginal):
+    streams, n_valid, lohi, w, mkw = _operands(one_chip, lanes, marginal,
+                                               room=True, n=n)
+    fn = maiz_topk_pallas if lanes is None else maiz_topk_pallas_b
+    _assert_kernel(fn.lower(*streams, n_valid, lohi, w, k=K,
+                            interpret=False, **mkw))
+
+
+def test_block_rule():
+    """Sweep 2's (blocks, tiles a block) for a node-tile count: one block
+    a lane for the benchmark cells (12 and 36 tiles), whole blocks of
+    MAX_BLOCK_TILES for a 512-tile shard."""
+    assert block_tiles(12) == (1, 12)
+    assert block_tiles(36) == (1, 36)
+    assert block_tiles(512) == (8, 64)
+    assert block_tiles(130) == (3, 44)
